@@ -13,9 +13,11 @@ run both plans, charging deployment cost to the policy that caused it.
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from typing import Dict, List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.adaptation import AdaptiveRunner, RunMetrics
@@ -27,6 +29,23 @@ from repro.core.patterns import (CompositePattern, Pattern, Predicate,
 from repro.data.cep_streams import StreamConfig, make_stream
 
 PATTERN_SETS = ["seq", "conj", "neg", "kleene", "composite"]
+
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left alone.  Otherwise the cache lives at ``<repo>/.jax_cache``: a
+    fixed path, since a cache whose directory moves never hits.  Returns
+    the directory in use.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def build_pattern(set_name: str, size: int, window: float = 4.0,

@@ -170,6 +170,9 @@ def main(argv=None) -> None:
     from . import (adaptive_moe, fig5_distance, fig69_methods,
                    fleet_bench, kernel_bench, roofline, rulebook_bench,
                    table1_davg)
+    from .common import enable_compile_cache
+
+    enable_compile_cache()
 
     sections = [
         ("fig5", "Figure 5 — throughput vs invariant distance d",

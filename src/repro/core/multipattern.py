@@ -660,7 +660,6 @@ def _shard_plane(fn, mesh, monitored: bool):
     specs are spelled per argument here."""
     if mesh is None:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from ..distributed.sharding import CEP_AXIS
@@ -673,8 +672,8 @@ def _shard_plane(fn, mesh, monitored: bool):
     else:
         in_specs = (kl, kl, rep, rep, kl, rep, rep)
         out_specs = (kl, kl)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

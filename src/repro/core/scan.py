@@ -453,7 +453,6 @@ def _shard_rulebook_scan(fn, mesh):
     collectives, sharding never changes semantics."""
     if mesh is None:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from ..distributed.sharding import CEP_AXIS
@@ -464,5 +463,5 @@ def _shard_rulebook_scan(fn, mesh):
     xs_spec = RulebookXs(chunk=skl, t0=rep, t1=rep, enabled=rep)
     in_specs = (kl, kl, rep, rep, kl, kl, xs_spec)
     out_specs = (kl, kl, skl)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -30,7 +30,6 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -90,8 +89,8 @@ def compressed_psum_tree(grads, ef_tree, mesh: Mesh, axis: str = "data"
         return tuple(outs) + tuple(nefs)
 
     specs = tuple(P() for _ in range(2 * len(leaves)))
-    fn = shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=specs, out_specs=specs,
+                       check_vma=False)
     res = fn(*leaves, *ef_leaves)
     n = len(leaves)
     return (jax.tree.unflatten(treedef, res[:n]),

@@ -244,10 +244,8 @@ def fleet_pspec(leading_k: bool = True) -> PartitionSpec:
 
 def shard_fleet_fn(fn, mesh: Mesh):
     """``shard_map`` a per-chunk fleet step: every arg/out leads with K."""
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=fleet_pspec(),
-                     out_specs=fleet_pspec(), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=fleet_pspec(),
+                         out_specs=fleet_pspec(), check_vma=False)
 
 
 def shard_fleet_scan(scan_fn, mesh: Mesh):
@@ -261,7 +259,6 @@ def shard_fleet_scan(scan_fn, mesh: Mesh):
     device-local ``lax.cond`` divergence — e.g. pass B running only on
     devices that own a migrating partition — is safe and free.
     """
-    from jax.experimental.shard_map import shard_map
 
     from ..core.scan import SuperchunkXs
 
@@ -271,8 +268,8 @@ def shard_fleet_scan(scan_fn, mesh: Mesh):
     xs_spec = SuperchunkXs(
         chunk=sk_led, t0=rep, t1=rep, enabled=rep,
         born_lo=sk_led, migrating=sk_led, old_sel=sk_led)
-    return shard_map(
+    return jax.shard_map(
         scan_fn, mesh=mesh,
         in_specs=(k_led, k_led, k_led, k_led, k_led, xs_spec),
         out_specs=(k_led, k_led, sk_led),
-        check_rep=False)
+        check_vma=False)

@@ -74,12 +74,9 @@ def shape_class(C: int, M: int, B: int) -> str:
 
 
 def platform() -> str:
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0].platform
-    except Exception:  # pragma: no cover - no backend at all
-        return "cpu"
+    return jax.devices()[0].platform
 
 
 def load_table(path: Optional[str] = None) -> Dict[str, dict]:

@@ -37,11 +37,7 @@ def default_backend() -> str:
             raise ValueError(f"REPRO_KERNEL_BACKEND={env!r} is not one of "
                              "'ref' | 'pallas' | 'interpret'")
         return env
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # pragma: no cover - no devices
-        platform = "cpu"
-    return "pallas" if platform == "tpu" else "ref"
+    return "pallas" if jax.devices()[0].platform == "tpu" else "ref"
 
 
 def set_backend(name: str) -> None:

@@ -1,29 +1,39 @@
-"""Pallas TPU kernel for the CEP masked windowed cross-join.
+"""Pallas TPU kernels for the CEP masked windowed cross-join.
 
 The hot loop of the vectorized CEP engine is, per evaluation-plan step, a
 dense cross-evaluation of ``C`` constraint rows between ``M`` partial matches
 and ``B`` buffered events:
 
-    ok[m, b] = AND_c cmp(op[c], L[c, m], R[c, b], theta[c]).
+    ok[m, b] = mvalid[m] & bvalid[b] & AND_c cmp(op[c], L[c, m], R[c, b],
+                                                  theta[c]).
+
+One kernel family serves every entry point: a mask kernel (the ``(M, B)``
+mask as int8, cast to bool by the wrapper) and a rowcount kernel (per-m
+counts reduced in VMEM, for negation and Kleene).  The unpacked join is
+the mask kernel with all-valid masks, the pair count is the sum of the
+row counts.
 
 TPU mapping
 -----------
-* Grid tiles the (M, B) output into ``(block_m, block_b)`` VMEM tiles
-  (default 128×128 — lane-aligned; the op is VPU-bound, 8×128 vregs).
-* The constraint dimension ``C`` is small (≈ 2·n + predicate pairs ≤ ~32);
-  each tile loads the full ``(C, block_m)`` / ``(C, block_b)`` operand strips
-  into VMEM — a few KiB — and unrolls the AND-reduction over ``C``
-  (``C`` is static at trace time; op-codes/thresholds are *data*, so one
-  compiled kernel serves every pattern/plan of a given size — plan changes
-  never recompile the data plane).
-* Output is ``int8`` 0/1 (TPU-safe dense mask); the wrapper casts to bool.
+* The grid tiles (M, B) into ``(block_m, block_b)`` tiles (default
+  128 x 128, or the autotune table's entry for the shape class).
+* ``C`` is small and static: each tile unrolls the AND over the rows.
+  Op codes and thresholds are *data*, so one compiled kernel serves every
+  plan of a pattern — plan changes never recompile the data plane.
+* Operand layouts are chosen for what Mosaic accepts (see the section
+  comment below): L transposed to ``(M, C)``, R as ``(C, B)``, op codes
+  and thresholds as 32-bit SMEM scalars, validity as int32 ``(M, 1)`` /
+  ``(1, B)`` masks.
 
-VMEM budget per tile: 2·C·128·4 B (operands) + 128·128 B (mask) ≈ 48 KiB at
-C = 32 — far under the ~16 MiB/core budget, leaving room for the pipeline's
-double buffering.
+VMEM per tile at C = 32: operands 2 x 32 x 128 x 4 B (lane-padded) plus
+the 128 x 128 mask, well under the scoped VMEM limit with double
+buffering.
 
-Validated against ``ref.window_join_ref`` in ``interpret=True`` mode on CPU
-(see ``tests/test_kernels.py``); TPU is the deployment target.
+Checked against the jnp oracle (``ref.py``) in interpret mode on CPU
+(``tests/test_kernels.py``, ``tests/test_packed_kernels.py``); compiled for
+a described v5e at engine widths, alone and under the fleet's and the
+rulebook's ``vmap`` (``tests/test_tpu_compile.py``); run on a TPU v5e
+chip through ``cep.open`` and ``cep.open_rulebook`` by ``chip_smoke.py``.
 """
 
 from __future__ import annotations
@@ -33,19 +43,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import autotune as _autotune
 from . import ref as _ref
-
-
-def _resolve_blocks(C, M, B, block_m, block_b):
-    """Static block sizes: explicit caller pins win, else the autotune
-    table (per shape-class, per platform), else (128, 128)."""
-    if block_m is None or block_b is None:
-        abm, abb = _autotune.best_blocks(C, M, B)
-        block_m = block_m if block_m is not None else abm
-        block_b = block_b if block_b is not None else abb
-    return block_m, block_b
 
 
 def _tile_waste(M, B, bm, bb) -> bool:
@@ -59,24 +60,137 @@ def _tile_waste(M, B, bm, bb) -> bool:
     return (M * B < 8 * 128) or (Mp * Bp >= 4 * M * B)
 
 
-def _kernel(l_ref, r_ref, op_ref, th_ref, out_ref):
-    C = l_ref.shape[0]
-    bm = l_ref.shape[1]
-    bb = r_ref.shape[1]
-    acc = jnp.ones((bm, bb), jnp.bool_)
-    for c in range(C):  # static unroll over the small constraint dim
-        l = l_ref[c, :][:, None]          # (bm, 1)
-        r = r_ref[c, :][None, :]          # (1, bb)
-        op = op_ref[c]
-        th = th_ref[c]
+# ---------------------------------------------------------------------------
+# One kernel family
+# ---------------------------------------------------------------------------
+#
+# Every entry point feeds the same tile body.  The operands are laid out so
+# that the TPU compiler (Mosaic) accepts the kernel alone and under the
+# engine's ``vmap`` over K partitions and (K, Q) rules:
+#
+# * L enters transposed, ``(M, C)``: a ``(block_m, C)`` tile yields each
+#   constraint column as a ``(block_m, 1)`` slice (sublane-major), R stays
+#   ``(C, B)`` and yields ``(1, block_b)`` rows — no 1-D -> 2-D reshape in
+#   the kernel;
+# * op-codes and thresholds are 32-bit scalars in SMEM, shaped ``(1, C)``
+#   so that the batch axes ``vmap`` prepends never touch the last two block
+#   dimensions;
+# * each constraint row is a mask-select over the three comparison planes —
+#   ``(lt & is_lt) | (gt & is_gt) | (ab & is_ab) | is_none`` — since Mosaic
+#   cannot select between boolean vectors;
+# * row validity enters as ``(M, 1)`` / ``(1, B)`` int32 masks seeding the
+#   accumulator.  Padding extends them with zeros, so padded (m, b) cells
+#   are excluded by construction for ANY op mix.
+#
+# The float comparisons are the exact expressions of ``ref.cmp_op``, so the
+# kernels agree bit-for-bit with the jnp oracle — the property the engine's
+# differential tests pin across the kernel switch.
+
+
+def _tile_mask(lt_ref, r_ref, op_ref, th_ref, mv_ref, bv_ref):
+    """(block_m, block_b) bool: validity AND every constraint row."""
+    acc = (mv_ref[...] > 0) & (bv_ref[...] > 0)
+    for c in range(r_ref.shape[0]):  # static unroll over the small C dim
+        l = lt_ref[:, c:c + 1]            # (bm, 1)
+        r = r_ref[c:c + 1, :]             # (1, bb)
+        op = op_ref[0, c]
+        th = th_ref[0, c]
         lt = l < r + th
         gt = l > r - th
         ab = jnp.abs(l - r) <= th
-        ok = jnp.where(
-            op == 1, lt, jnp.where(op == 2, gt, jnp.where(op == 3, ab, True))
-        )
-        acc = jnp.logical_and(acc, ok)
-    out_ref[...] = acc.astype(jnp.int8)
+        ok = (lt & (op == 1)) | (gt & (op == 2)) | (ab & (op == 3)) \
+            | (op == 0)
+        acc = acc & ok
+    return acc
+
+
+def _mask_kernel(lt_ref, r_ref, op_ref, th_ref, mv_ref, bv_ref, out_ref):
+    out_ref[...] = _tile_mask(lt_ref, r_ref, op_ref, th_ref, mv_ref,
+                              bv_ref).astype(jnp.int8)
+
+
+def _rowcount_kernel(lt_ref, r_ref, op_ref, th_ref, mv_ref, bv_ref,
+                     out_ref):
+    """Per-m surviving-pair counts, accumulated across the B-tile grid.
+
+    The (bm, bb) mask never leaves VMEM: each tile reduces over its lanes
+    and accumulates into the (bm, 1) output block, which the sequential
+    j-sweep of the grid revisits.
+    """
+    j = pl.program_id(1)
+    acc = _tile_mask(lt_ref, r_ref, op_ref, th_ref, mv_ref, bv_ref)
+    partial = acc.astype(jnp.int32).sum(axis=1, keepdims=True)  # (bm, 1)
+
+    @pl.when(j == 0)
+    def _init():
+        out_ref[...] = partial
+
+    @pl.when(j != 0)
+    def _accum():
+        out_ref[...] = out_ref[...] + partial
+
+
+def _blocks(C, M, B, block_m, block_b):
+    """Static tiles (bm, bb) and padded extents (Mp, Bp).  Explicit caller
+    pins win, else the autotune table (per shape class, per platform),
+    else (128, 128); tiles are clamped to the operand."""
+    if block_m is None or block_b is None:
+        abm, abb = _autotune.best_blocks(C, M, B)
+        block_m = block_m if block_m is not None else abm
+        block_b = block_b if block_b is not None else abb
+    bm = min(block_m, max(M, 8))
+    bb = min(block_b, max(B, 128))
+    return bm, bb, (M + bm - 1) // bm * bm, (B + bb - 1) // bb * bb
+
+
+def _unpacked_ops(ops):
+    """Unpacked op semantics (any code outside {1, 2, 3} is vacuous True)
+    restated as the mask-select's codes: such codes become NONE (0)."""
+    ops = ops.astype(jnp.int32)
+    return jnp.where((ops >= 1) & (ops <= 3), ops, 0)
+
+
+def _tiled(L, R, ops, thetas, mvalid, bvalid, tiles, interpret, *,
+           rowcount=False):
+    """Pad to whole tiles, lay the operands out and run the mask kernel,
+    or with ``rowcount`` the rowcount kernel.
+
+    Returns the padded output: the ``(Mp, Bp)`` int8 mask, or with
+    ``rowcount`` the ``(Mp, 1)`` int32 per-m counts.
+    """
+    bm, bb, Mp, Bp = tiles
+    C, M = L.shape
+    B = R.shape[1]
+    Lt = jnp.pad(L.astype(jnp.float32).T, ((0, Mp - M), (0, 0)))
+    Rp = jnp.pad(R.astype(jnp.float32), ((0, 0), (0, Bp - B)))
+    # Validity doubles as the padding mask: padded slots are invalid rows.
+    mv = jnp.pad(mvalid.astype(jnp.int32), (0, Mp - M))[:, None]
+    bv = jnp.pad(bvalid.astype(jnp.int32), (0, Bp - B))[None, :]
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    if rowcount:
+        kernel = _rowcount_kernel
+        out_spec = pl.BlockSpec((bm, 1), lambda i, j: (i, 0))
+        out_shape = jax.ShapeDtypeStruct((Mp, 1), jnp.int32)
+    else:
+        kernel = _mask_kernel
+        out_spec = pl.BlockSpec((bm, bb), lambda i, j: (i, j))
+        out_shape = jax.ShapeDtypeStruct((Mp, Bp), jnp.int8)
+    return pl.pallas_call(
+        kernel,
+        grid=(Mp // bm, Bp // bb),
+        in_specs=[
+            pl.BlockSpec((bm, C), lambda i, j: (i, 0)),
+            pl.BlockSpec((C, bb), lambda i, j: (0, j)),
+            smem,
+            smem,
+            pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, bb), lambda i, j: (0, j)),
+        ],
+        out_specs=out_spec,
+        out_shape=out_shape,
+        interpret=interpret,
+    )(Lt, Rp, ops.astype(jnp.int32)[None, :],
+      thetas.astype(jnp.float32)[None, :], mv, bv)
 
 
 @functools.partial(
@@ -95,170 +209,17 @@ def window_join_pallas(
     """Tiled Pallas evaluation of the constraint cross-join.
 
     L: (C, M) f32, R: (C, B) f32, ops: (C,) i32, thetas: (C,) f32.
-    Returns ok: (M, B) bool.  M and B are padded up to tile multiples
-    internally; padding garbage is sliced away before returning.
-    Block sizes default to the autotune table for the shape class.
-    Interpret mode always runs the kernel body (it is the correctness
-    harness); compiled mode falls back to the jnp reference for shapes
-    that would be mostly tile padding.
+    Returns ok: (M, B) bool: the packed join with every row valid.  M and
+    B are padded up to tile multiples internally; padding is sliced away
+    before returning.  Block sizes default to the autotune table for the
+    shape class.  Interpret mode always runs the kernel body (it is the
+    correctness harness); compiled mode falls back to the jnp reference
+    for shapes that would be mostly tile padding.
     """
-    C, M = L.shape
-    _, B = R.shape
-    block_m, block_b = _resolve_blocks(C, M, B, block_m, block_b)
-    bm = min(block_m, max(M, 8))
-    bb = min(block_b, max(B, 128))
-    if not interpret and _tile_waste(M, B, bm, bb):
-        return _ref.window_join_ref(L, R, ops, thetas)
-    Mp = (M + bm - 1) // bm * bm
-    Bp = (B + bb - 1) // bb * bb
-    if Mp != M:
-        L = jnp.pad(L, ((0, 0), (0, Mp - M)))
-    if Bp != B:
-        R = jnp.pad(R, ((0, 0), (0, Bp - B)))
-
-    grid = (Mp // bm, Bp // bb)
-    out = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((C, bm), lambda i, j: (0, i)),
-            pl.BlockSpec((C, bb), lambda i, j: (0, j)),
-            pl.BlockSpec((C,), lambda i, j: (0,)),
-            pl.BlockSpec((C,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, bb), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Bp), jnp.int8),
-        interpret=interpret,
-    )(
-        L.astype(jnp.float32),
-        R.astype(jnp.float32),
-        ops.astype(jnp.int32),
-        thetas.astype(jnp.float32),
-    )
-    return out[:M, :B].astype(jnp.bool_)
-
-
-def _count_kernel(l_ref, r_ref, op_ref, th_ref, out_ref, *, m_valid, b_valid):
-    """Per-tile match counting — avoids materializing ok to HBM when only
-    cardinalities are needed (statistics estimation, §2.2).
-
-    ``m_valid`` / ``b_valid`` are the true (unpadded) extents, static at
-    trace time.  Padded (m, b) cells are masked out explicitly: a pure
-    value-based pad (e.g. NaN) only dies on rows whose op actually
-    *compares* — an op ∉ {1, 2, 3} row takes the vacuous-True branch, so a
-    constraint stack of only NONE rows would count the padding.
-    """
-    C = l_ref.shape[0]
-    bm = l_ref.shape[1]
-    bb = r_ref.shape[1]
-    mi = pl.program_id(0) * bm + jax.lax.broadcasted_iota(
-        jnp.int32, (bm, bb), 0)
-    bi = pl.program_id(1) * bb + jax.lax.broadcasted_iota(
-        jnp.int32, (bm, bb), 1)
-    acc = (mi < m_valid) & (bi < b_valid)
-    for c in range(C):
-        l = l_ref[c, :][:, None]
-        r = r_ref[c, :][None, :]
-        op = op_ref[c]
-        th = th_ref[c]
-        lt = l < r + th
-        gt = l > r - th
-        ab = jnp.abs(l - r) <= th
-        ok = jnp.where(
-            op == 1, lt, jnp.where(op == 2, gt, jnp.where(op == 3, ab, True))
-        )
-        acc = jnp.logical_and(acc, ok)
-    out_ref[0, 0] = jnp.sum(acc.astype(jnp.int32))
-
-
-@functools.partial(
-    jax.jit, static_argnames=("block_m", "block_b", "interpret")
-)
-def window_join_count_pallas(
-    L, R, ops, thetas, *, block_m: int | None = None,
-    block_b: int | None = None, interpret: bool = False,
-) -> jax.Array:
-    """Total number of matching (m, b) pairs, computed tile-locally."""
-    C, M = L.shape
-    _, B = R.shape
-    block_m, block_b = _resolve_blocks(C, M, B, block_m, block_b)
-    bm = min(block_m, max(M, 8))
-    bb = min(block_b, max(B, 128))
-    if not interpret and _tile_waste(M, B, bm, bb):
-        return _ref.window_join_ref(L, R, ops, thetas).sum(
-            dtype=jnp.int32)
-    Mp = (M + bm - 1) // bm * bm
-    Bp = (B + bb - 1) // bb * bb
-    # Padding exactness: the kernel masks every (m, b) cell against the true
-    # extents (static at trace time), so pad *values* are irrelevant — they
-    # can never be counted, whatever the op codes are.  (An earlier NaN-pad
-    # scheme relied on padded values failing a comparison, which a
-    # vacuous-True op ∉ {1, 2, 3} row never performs.)
-    if Mp != M:
-        L = jnp.pad(L, ((0, 0), (0, Mp - M)))
-    if Bp != B:
-        R = jnp.pad(R, ((0, 0), (0, Bp - B)))
-    grid = (Mp // bm, Bp // bb)
-    counts = pl.pallas_call(
-        functools.partial(_count_kernel, m_valid=M, b_valid=B),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((C, bm), lambda i, j: (0, i)),
-            pl.BlockSpec((C, bb), lambda i, j: (0, j)),
-            pl.BlockSpec((C,), lambda i, j: (0,)),
-            pl.BlockSpec((C,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp // bm, Bp // bb), jnp.int32),
-        interpret=interpret,
-    )(
-        L.astype(jnp.float32),
-        R.astype(jnp.float32),
-        ops.astype(jnp.int32),
-        thetas.astype(jnp.float32),
-    )
-    return counts.sum()
-
-
-# ---------------------------------------------------------------------------
-# Packed operand layout
-# ---------------------------------------------------------------------------
-#
-# The packed variants take the engine's cached-strip layout:
-#
-# * op-codes enter as an ``int8`` strip and each constraint row is a
-#   mask-select over the three precomputed comparison planes —
-#   ``(lt & is_lt) | (gt & is_gt) | (ab & is_ab) | is_none`` — instead of
-#   the unpacked kernel's nested ``jnp.where`` dispatch;
-# * row-validity enters as two ``int8`` vectors seeding the accumulator,
-#   not as two float32 constraint rows — the constraint stack shrinks by
-#   two planes and, because padding extends the validity vectors with
-#   zeros, padded (m, b) cells are excluded by construction (no iota
-#   masking needed, for ANY op mix);
-# * the AND-reduction accumulates in bool/int8 vregs throughout.
-#
-# The float comparisons are the exact unpacked expressions, so packed and
-# unpacked agree bit-for-bit — the property the engine's differential
-# tests pin across the kernel switch.
-
-
-def _packed_kernel(l_ref, r_ref, op_ref, th_ref, mv_ref, bv_ref, out_ref):
-    C = l_ref.shape[0]
-    mv = mv_ref[0, :] > 0                     # (bm,)
-    bv = bv_ref[0, :] > 0                     # (bb,)
-    acc = mv[:, None] & bv[None, :]           # (bm, bb) bool
-    for c in range(C):  # static unroll over the small constraint dim
-        l = l_ref[c, :][:, None]
-        r = r_ref[c, :][None, :]
-        op = op_ref[c]
-        th = th_ref[c]
-        lt = l < r + th
-        gt = l > r - th
-        ab = jnp.abs(l - r) <= th
-        ok = (lt & (op == 1)) | (gt & (op == 2)) | (ab & (op == 3)) \
-            | (op == 0)
-        acc = jnp.logical_and(acc, ok)
-    out_ref[...] = acc.astype(jnp.int8)
+    return window_join_packed_pallas(
+        L, R, _unpacked_ops(ops), thetas, jnp.ones(L.shape[1:], jnp.int32),
+        jnp.ones(R.shape[1:], jnp.int32), block_m=block_m, block_b=block_b,
+        interpret=interpret)
 
 
 @functools.partial(
@@ -274,83 +235,13 @@ def window_join_packed_pallas(
     mvalid: (M,), bvalid: (B,) i8/bool.  Returns (M, B) bool.
     """
     C, M = L.shape
-    _, B = R.shape
-    block_m, block_b = _resolve_blocks(C, M, B, block_m, block_b)
-    bm = min(block_m, max(M, 8))
-    bb = min(block_b, max(B, 128))
-    if not interpret and _tile_waste(M, B, bm, bb):
+    B = R.shape[1]
+    tiles = _blocks(C, M, B, block_m, block_b)
+    if not interpret and _tile_waste(M, B, tiles[0], tiles[1]):
         return _ref.window_join_packed_ref(L, R, ops8, thetas, mvalid,
                                            bvalid)
-    Mp = (M + bm - 1) // bm * bm
-    Bp = (B + bb - 1) // bb * bb
-    if Mp != M:
-        L = jnp.pad(L, ((0, 0), (0, Mp - M)))
-    if Bp != B:
-        R = jnp.pad(R, ((0, 0), (0, Bp - B)))
-    # Validity doubles as the padding mask: padded slots are invalid rows.
-    mv = jnp.pad(mvalid.astype(jnp.int8), (0, Mp - M))[None, :]
-    bv = jnp.pad(bvalid.astype(jnp.int8), (0, Bp - B))[None, :]
-
-    grid = (Mp // bm, Bp // bb)
-    out = pl.pallas_call(
-        _packed_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((C, bm), lambda i, j: (0, i)),
-            pl.BlockSpec((C, bb), lambda i, j: (0, j)),
-            pl.BlockSpec((C,), lambda i, j: (0,)),
-            pl.BlockSpec((C,), lambda i, j: (0,)),
-            pl.BlockSpec((1, bm), lambda i, j: (0, i)),
-            pl.BlockSpec((1, bb), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bb), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Bp), jnp.int8),
-        interpret=interpret,
-    )(
-        L.astype(jnp.float32),
-        R.astype(jnp.float32),
-        ops8.astype(jnp.int8),
-        thetas.astype(jnp.float32),
-        mv,
-        bv,
-    )
+    out = _tiled(L, R, ops8, thetas, mvalid, bvalid, tiles, interpret)
     return out[:M, :B].astype(jnp.bool_)
-
-
-def _rowcount_kernel(l_ref, r_ref, op_ref, th_ref, out_ref, *, b_valid):
-    """Per-m surviving-pair counts, accumulated across the B-tile grid.
-
-    The (bm, bb) mask never leaves VMEM: each tile reduces over its lanes
-    and accumulates into the (bm, 1) output block, which the sequential
-    j-sweep of the grid revisits.  ``b_valid`` (true B extent, static)
-    masks lane padding; m padding needs no mask — the wrapper slices it.
-    """
-    C = l_ref.shape[0]
-    bm = l_ref.shape[1]
-    bb = r_ref.shape[1]
-    j = pl.program_id(1)
-    bi = j * bb + jax.lax.broadcasted_iota(jnp.int32, (bm, bb), 1)
-    acc = bi < b_valid
-    for c in range(C):
-        l = l_ref[c, :][:, None]
-        r = r_ref[c, :][None, :]
-        op = op_ref[c]
-        th = th_ref[c]
-        lt = l < r + th
-        gt = l > r - th
-        ab = jnp.abs(l - r) <= th
-        ok = (lt & (op == 1)) | (gt & (op == 2)) | (ab & (op == 3)) \
-            | (op == 0)
-        acc = jnp.logical_and(acc, ok)
-    partial = acc.astype(jnp.int32).sum(axis=1, keepdims=True)  # (bm, 1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = partial
-
-    @pl.when(j != 0)
-    def _accum():
-        out_ref[...] = out_ref[...] + partial
 
 
 @functools.partial(
@@ -367,35 +258,25 @@ def window_join_rowcount_pallas(
     never materialized to HBM.
     """
     C, M = L.shape
-    _, B = R.shape
-    block_m, block_b = _resolve_blocks(C, M, B, block_m, block_b)
-    bm = min(block_m, max(M, 8))
-    bb = min(block_b, max(B, 128))
-    if not interpret and _tile_waste(M, B, bm, bb):
+    B = R.shape[1]
+    tiles = _blocks(C, M, B, block_m, block_b)
+    if not interpret and _tile_waste(M, B, tiles[0], tiles[1]):
         return _ref.window_join_rowcount_ref(L, R, ops, thetas)
-    Mp = (M + bm - 1) // bm * bm
-    Bp = (B + bb - 1) // bb * bb
-    if Mp != M:
-        L = jnp.pad(L, ((0, 0), (0, Mp - M)))
-    if Bp != B:
-        R = jnp.pad(R, ((0, 0), (0, Bp - B)))
-    grid = (Mp // bm, Bp // bb)
-    counts = pl.pallas_call(
-        functools.partial(_rowcount_kernel, b_valid=B),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((C, bm), lambda i, j: (0, i)),
-            pl.BlockSpec((C, bb), lambda i, j: (0, j)),
-            pl.BlockSpec((C,), lambda i, j: (0,)),
-            pl.BlockSpec((C,), lambda i, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Mp, 1), jnp.int32),
-        interpret=interpret,
-    )(
-        L.astype(jnp.float32),
-        R.astype(jnp.float32),
-        ops.astype(jnp.int32),
-        thetas.astype(jnp.float32),
-    )
+    counts = _tiled(L, R, _unpacked_ops(ops), thetas,
+                    jnp.ones((M,), jnp.int32), jnp.ones((B,), jnp.int32),
+                    tiles, interpret, rowcount=True)
     return counts[:M, 0]
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_m", "block_b", "interpret")
+)
+def window_join_count_pallas(
+    L, R, ops, thetas, *, block_m: int | None = None,
+    block_b: int | None = None, interpret: bool = False,
+) -> jax.Array:
+    """Total number of matching (m, b) pairs: the sum of the fused row
+    counts, so the mask never reaches HBM."""
+    return window_join_rowcount_pallas(
+        L, R, ops, thetas, block_m=block_m, block_b=block_b,
+        interpret=interpret).sum()

@@ -175,7 +175,6 @@ def _moe_ffn_ep(x, p, cfg: ModelConfig, mesh, expert_perm=None):
     all_to_all sends each expert group to its owner -> local-expert SwiGLU
     over (E_loc, n_ep*C_loc, D) -> reverse all_to_all -> weighted combine.
     """
-    from jax.experimental.shard_map import shard_map
 
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
@@ -258,13 +257,13 @@ def _moe_ffn_ep(x, p, cfg: ModelConfig, mesh, expert_perm=None):
         return out.reshape(x_loc.shape), aux, load
 
     bspec = batch_axes[0] if len(batch_axes) == 1 else batch_axes
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None), P(None)),
         out_specs=(P(bspec, None, None), P(), P()),
-        check_rep=False)
+        check_vma=False)
     out, aux, load = fn(x, p["router"], p["w_gate"], p["w_up"],
                         p["w_down"], perm)
 
