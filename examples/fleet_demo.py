@@ -50,8 +50,6 @@ print(f"== fleet of {K} tenants, {tel.chunks} chunks, "
 print(f"matches={tel.matches}  replans={tel.replans}  "
       f"deployments={tel.deployments}  "
       f"migrating-partition-chunks={tel.migration_partition_chunks}")
-print(f"engine {tel.engine_time_s * 1e3:.0f} ms, "
-      f"control {tel.control_time_s * 1e3:.0f} ms")
 
 print(f"\n{'tenant':>6s} {'regime':>8s} {'matches':>8s} {'oracle':>8s}")
 oracle = [RefEngine(pattern.build()).run(s).full_matches

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import spans
 from ..core.engine import Chunk, EngineConfig, make_spec
 from ..core.fleet import stack_chunks
 from ..core.greedy import greedy_order_plan
@@ -607,27 +608,38 @@ class Rulebook:
         counts as an (R, K) array over rules in insertion order (removed
         rules contribute zero rows).  Monitored rulebooks also run the
         violation → sync → replan → row-deploy loop per flagged (q, k)
-        cell inside the call.
+        cell inside the call.  The tick writes the session's span names
+        (``core.spans``), with the tick index as argument.
         """
-        chunk = self._check_chunk(chunk)
+        tick = self._chunks
+        with jax.profiler.TraceAnnotation(spans.PROCESS, chunk=tick):
+            return self._step(chunk, t0, t1, tick)
+
+    def _step(self, chunk: Chunk, t0: float, t1: float,
+              tick: int) -> np.ndarray:
+        with jax.profiler.TraceAnnotation(spans.ROUTE, chunk=tick):
+            chunk = self._check_chunk(chunk)
         t0j, t1j = jnp.float32(t0), jnp.float32(t1)
         self._chunks += 1
         out = np.zeros((len(self._rules), self.k), np.int64)
         for bucket in self._buckets:
-            if self.monitored:
-                (bucket.state, bucket.monitor, res, violated, _drift,
-                 rates, sel) = bucket.plane.fn(
-                     bucket.state, bucket.monitor, chunk,
-                     bucket.ops_device(), bucket.share_d,
-                     bucket.plans_device(),
-                     bucket.lowered.device(), t0j, t1j)
-            else:
-                bucket.state, res = bucket.plane.fn(
-                    bucket.state, chunk, bucket.ops_device(),
-                    bucket.share_d, bucket.plans_device(), t0j, t1j)
+            with jax.profiler.TraceAnnotation(spans.STEP, chunk=tick):
+                if self.monitored:
+                    (bucket.state, bucket.monitor, res, violated, _drift,
+                     rates, sel) = bucket.plane.fn(
+                         bucket.state, bucket.monitor, chunk,
+                         bucket.ops_device(), bucket.share_d,
+                         bucket.plans_device(),
+                         bucket.lowered.device(), t0j, t1j)
+                else:
+                    bucket.state, res = bucket.plane.fn(
+                        bucket.state, chunk, bucket.ops_device(),
+                        bucket.share_d, bucket.plans_device(), t0j, t1j)
             # One coalesced counter transfer per bucket per tick.
-            cnt = np.asarray(jnp.stack(
-                [res.full, res.pm, res.overflow, res.closure, res.neg]))
+            with jax.profiler.TraceAnnotation(spans.READBACK, chunk=tick):
+                cnt = np.asarray(jnp.stack(
+                    [res.full, res.pm, res.overflow, res.closure,
+                     res.neg]))
             self._host_syncs += 1
             for q, entry in enumerate(bucket.slots):
                 if entry is None or not entry.active:
@@ -641,17 +653,26 @@ class Rulebook:
                 entry.chunks += 1
                 out[entry.rid] = full_k
             if self.monitored:
-                fired = np.nonzero(np.asarray(violated))
-                if fired[0].size:
-                    # One coalesced stats transfer serves every fired
-                    # cell; per-cell device indexing costs a sync each.
-                    self._host_syncs += 1
-                    rates_h = np.asarray(rates, np.float64)
-                    sel_h = np.asarray(sel, np.float64)
-                    for k, q in zip(*fired):
-                        self._replan_cell(bucket, int(k), int(q),
-                                          rates_h, sel_h)
+                with jax.profiler.TraceAnnotation(spans.READBACK, chunk=tick):
+                    fired = np.nonzero(np.asarray(violated))
+                with jax.profiler.TraceAnnotation(spans.CONTROL, chunk=tick):
+                    self._apply_flags(bucket, fired, rates, sel, tick)
         return out
+
+    def _apply_flags(self, bucket: _Bucket, fired, rates, sel,
+                     tick: int) -> None:
+        if not fired[0].size:
+            return
+        # One coalesced stats transfer serves every fired cell; per-cell
+        # device indexing costs a sync each.
+        self._host_syncs += 1
+        with jax.profiler.TraceAnnotation(spans.READBACK, chunk=tick):
+            rates_h = np.asarray(rates, np.float64)
+            sel_h = np.asarray(sel, np.float64)
+        for k, q in zip(*fired):
+            with jax.profiler.TraceAnnotation(
+                    spans.REPLAN, chunk=tick, partition=int(k)):
+                self._replan_cell(bucket, int(k), int(q), rates_h, sel_h)
 
     def _replan_cell(self, bucket: _Bucket, k: int, q: int,
                      rates, sel) -> None:

@@ -45,8 +45,10 @@ import dataclasses
 import itertools
 from typing import Iterable, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
+from ..core import spans
 from ..core.adaptation import make_planner
 from ..core.compat import legacy_ok
 from ..core.engine import Chunk
@@ -67,6 +69,7 @@ _COUNTERS = (
     "chunks", "events", "matches", "replans", "deployments", "violations",
     "host_syncs", "overflow", "dropped", "neg_rejected",
     "closure_expansions", "escalations", "migration_partition_chunks",
+    "readbacks",
 )
 
 
@@ -78,6 +81,10 @@ class Telemetry:
     for OR-composites); ``per_partition_matches`` keeps the (K,) split.
     ``violations``/``host_syncs`` are nonzero only for monitored sessions;
     ``dropped`` counts keyed-batch routing overflow (back-pressure).
+    ``readbacks`` counts the blocking device→host reads of the
+    incremental plane: one for the slice's counters, one for a monitored
+    session's flags and drift, and two (``rates[p]``, ``sel[p]``) per
+    flagged partition.
     ``events`` is maintained by ``run`` and ``process`` — ``step`` skips
     it to avoid a per-tick device sync.
     """
@@ -97,8 +104,7 @@ class Telemetry:
     closure_expansions: int = 0
     escalations: int = 0
     migration_partition_chunks: int = 0
-    engine_time_s: float = 0.0
-    control_time_s: float = 0.0
+    readbacks: int = 0
     last_drift: Optional[np.ndarray] = None
     branches: Optional[Tuple["Telemetry", ...]] = None
 
@@ -106,8 +112,6 @@ class Telemetry:
         """Accumulate ``other`` into self (counters add, arrays add)."""
         for f in _COUNTERS:
             setattr(self, f, getattr(self, f) + getattr(other, f))
-        self.engine_time_s += other.engine_time_s
-        self.control_time_s += other.control_time_s
         if other.per_partition_matches is not None:
             if self.per_partition_matches is None:
                 self.per_partition_matches = np.zeros(
@@ -136,8 +140,6 @@ def _from_fleet_metrics(m: FleetMetrics, k: int) -> Telemetry:
         closure_expansions=m.closure_expansions,
         escalations=m.escalations,
         migration_partition_chunks=m.migration_partition_chunks,
-        engine_time_s=m.engine_time_s,
-        control_time_s=m.control_time_s,
         last_drift=(None if m.last_drift is None else m.last_drift.copy()),
     )
 
@@ -379,17 +381,25 @@ class Session:
     def process(self, type_id, ts, attr, keys, t0: float,
                 t1: float) -> np.ndarray:
         """Route one keyed event batch (``key % K``) covering ``(t0, t1]``
-        and tick the fleet once; returns per-partition match counts."""
+        and tick the fleet once; returns per-partition match counts.
+
+        The call is one ``cep.process`` span, the parent of the serving
+        engine's spans of the slice (``core.spans``)."""
         if self.is_composite:
-            self._tel.chunks += 1
-            self._tel.events += int(len(np.asarray(type_id)))
-            return sum(b.process(type_id, ts, attr, keys, t0, t1)
-                       for b in self.branches)
-        eng = self._ensure_serving()
+            tick = self._tel.chunks
+        else:
+            tick = self._ensure_serving().ticks
+        with jax.profiler.TraceAnnotation(spans.PROCESS, chunk=tick):
+            return self._process(type_id, ts, attr, keys, t0, t1)
+
+    def _process(self, type_id, ts, attr, keys, t0, t1) -> np.ndarray:
         self._tel.chunks += 1
         self._tel.events += int(len(np.asarray(type_id)))
-        return eng.process_batch(type_id, ts, attr, keys,
-                                 float(t0), float(t1))
+        if self.is_composite:
+            return sum(b._process(type_id, ts, attr, keys, t0, t1)
+                       for b in self.branches)
+        return self._ensure_serving().process_batch(
+            type_id, ts, attr, keys, float(t0), float(t1))
 
     def deploy(self, partition: int, plan) -> None:
         """Deploy an evaluation plan for one partition: a stacked-matrix
@@ -430,6 +440,7 @@ class Session:
         tel.neg_rejected = int(eng.neg_rejected.sum())
         tel.closure_expansions = int(eng.closure_expansions.sum())
         tel.dropped = int(eng.dropped)
+        tel.readbacks = int(eng.readbacks)
         if self.monitor:
             tel.violations = int(eng.violations.sum())
             tel.replans = int(eng.replans.sum())

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops as kops
+from . import spans
 from .patterns import PRED_GT, PRED_LT, PRED_NONE, Pattern
 from .plans import OrderPlan, TreeNode, TreePlan
 
@@ -159,6 +160,7 @@ def _pred_rows(spec, L: MatchSet, R: MatchSet):
     return rows
 
 
+@jax.named_scope(spans.COMPACT)
 def _compact(L: MatchSet, R: MatchSet, ok, pm_created, out_cap: int):
     """Prefix-sum compaction of the surviving (m, b) pairs into a MatchSet."""
     m = L.valid.shape[0]
@@ -188,15 +190,17 @@ def _join(spec, cfg, L: MatchSet, R: MatchSet, order_rows, out_cap: int):
     """One plan step: constraint cross-join + compaction."""
     m = L.valid.shape[0]
     b = R.valid.shape[0]
-    rows = (
-        _validity_rows(L.valid, R.valid, m, b)
-        + _window_rows(L.min_ts, L.max_ts, R.min_ts, R.max_ts, spec.window)
-        + order_rows
-        + _pred_rows(spec, L, R)
-    )
-    Ls, Rs, ops_, ths = _rows_to_stacks(rows, m, b)
-    ok = kops.window_join(Ls, Rs, ops_, ths, backend=cfg.backend)
-    pm_created = ok.sum().astype(jnp.int32)
+    with jax.named_scope(spans.JOIN):
+        rows = (
+            _validity_rows(L.valid, R.valid, m, b)
+            + _window_rows(L.min_ts, L.max_ts, R.min_ts, R.max_ts,
+                           spec.window)
+            + order_rows
+            + _pred_rows(spec, L, R)
+        )
+        Ls, Rs, ops_, ths = _rows_to_stacks(rows, m, b)
+        ok = kops.window_join(Ls, Rs, ops_, ths, backend=cfg.backend)
+        pm_created = ok.sum().astype(jnp.int32)
     return _compact(L, R, ok, pm_created, out_cap)
 
 
@@ -286,6 +290,7 @@ def init_buffers(spec: _Spec, cfg: EngineConfig) -> Buffers:
     )
 
 
+@jax.named_scope(spans.INGEST)
 def _ingest(spec: _Spec, cfg: EngineConfig, buffers: Buffers,
             chunk: Chunk) -> Buffers:
     """Route chunk events into their per-type ring buffers."""
@@ -338,6 +343,7 @@ def _leaf(spec: _Spec, cfg: EngineConfig, buffers: Buffers, row, pos,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope(spans.FINALIZE)
 def _finalize(spec: _Spec, cfg: EngineConfig, buffers: Buffers,
               pm: MatchSet, t0, t1, born_lo,
               born_hi) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -567,22 +573,23 @@ class OrderEngine:
 
         def packed_step(buffers, pm, q, sops, lo, hi, t0):
             """gather + packed kernel + compaction — one plan step."""
-            R = _leaf(spec, cfg, buffers, q, q, t0, cfg.b_cap)
-            attr_b = buffers.attr[q]
-            Lr = [pm.max_ts, pm.min_ts]
-            Rr = [R.min_ts, R.max_ts]
-            if spec.is_seq:
-                Lr += [pm.ts[:, lo], pm.ts[:, hi]]
-                Rr += [R.min_ts, R.min_ts]
-            for (a, _b, ac, bc) in pred_cols:
-                Lr.append(pm.attr[:, a, ac])
-                Rr.append(attr_b[:, bc])
-            Ls = jnp.stack([x.astype(jnp.float32) for x in Lr])
-            Rs = jnp.stack([x.astype(jnp.float32) for x in Rr])
-            ok = kops.window_join_packed(Ls, Rs, sops, ths_const,
-                                         pm.valid, R.valid,
-                                         backend=cfg.backend)
-            created = ok.sum().astype(jnp.int32)
+            with jax.named_scope(spans.JOIN):
+                R = _leaf(spec, cfg, buffers, q, q, t0, cfg.b_cap)
+                attr_b = buffers.attr[q]
+                Lr = [pm.max_ts, pm.min_ts]
+                Rr = [R.min_ts, R.max_ts]
+                if spec.is_seq:
+                    Lr += [pm.ts[:, lo], pm.ts[:, hi]]
+                    Rr += [R.min_ts, R.min_ts]
+                for (a, _b, ac, bc) in pred_cols:
+                    Lr.append(pm.attr[:, a, ac])
+                    Rr.append(attr_b[:, bc])
+                Ls = jnp.stack([x.astype(jnp.float32) for x in Lr])
+                Rs = jnp.stack([x.astype(jnp.float32) for x in Rr])
+                ok = kops.window_join_packed(Ls, Rs, sops, ths_const,
+                                             pm.valid, R.valid,
+                                             backend=cfg.backend)
+                created = ok.sum().astype(jnp.int32)
             return _compact(pm, R, ok, created, cfg.m_cap)
 
         def process(buffers: Buffers, chunk: Chunk, plan, t0, t1,
@@ -772,13 +779,16 @@ def make_monitored_process(process_fn, spec: _Spec, laplace: float = 1.0):
                  born_lo, born_hi):
         buffers, res = process_fn(buffers, chunk, plan, t0, t1,
                                   born_lo, born_hi)
-        counts, trials, hits = chunk_observations(
-            chunk.type_id, chunk.attr, chunk.valid, spec.type_ids,
-            {"op": spec.op_t, "a_attr": spec.a_attr_t,
-             "b_attr": spec.b_attr_t, "theta": spec.theta_t})
-        monitor = monitor_update(monitor, counts, t1 - t0, trials, hits)
-        rates, sel = monitor_snapshot(monitor, laplace)
-        violated, drift = eval_lowered(lowered, rates, sel)
+        with jax.named_scope(spans.MONITOR):
+            counts, trials, hits = chunk_observations(
+                chunk.type_id, chunk.attr, chunk.valid, spec.type_ids,
+                {"op": spec.op_t, "a_attr": spec.a_attr_t,
+                 "b_attr": spec.b_attr_t, "theta": spec.theta_t})
+            monitor = monitor_update(monitor, counts, t1 - t0, trials,
+                                     hits)
+            rates, sel = monitor_snapshot(monitor, laplace)
+        with jax.named_scope(spans.VERIFY):
+            violated, drift = eval_lowered(lowered, rates, sel)
         return buffers, monitor, res, violated, drift, rates, sel
 
     return mprocess

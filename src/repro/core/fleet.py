@@ -37,7 +37,6 @@ statistics; see ``tests/test_fleet.py`` and ``tests/test_monitor.py``.
 from __future__ import annotations
 
 import dataclasses
-import time
 from collections import OrderedDict
 from typing import Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -45,6 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
 from .decision import DecisionPolicy, InvariantPolicy
 from .engine import (NEG_INF, POS_INF, Buffers, Chunk, EngineConfig,
                      OrderEngine, StepResult, TreeEngine,
@@ -399,8 +399,6 @@ class FleetMetrics:
     deployments: int = 0
     escalations: int = 0
     migration_partition_chunks: int = 0
-    engine_time_s: float = 0.0
-    control_time_s: float = 0.0
     violations: int = 0            # device invariant flags fired
     host_syncs: int = 0            # per-partition statistic pulls
     per_partition_matches: Optional[np.ndarray] = None
@@ -630,43 +628,42 @@ class FleetRunner:
         adaptive = any(pol is not None for pol in self.policies)
 
         for fc in fleet_stream:
-            t_ctl = time.perf_counter()
-            if adaptive or any(pl is None for pl in self.cur_plans):
-                if adaptive:
-                    self._observe(fc)
-                for p in range(self.k):
-                    self._replan_partition(
-                        p, self.estimator.snapshot(p), fc.t0, m)
-            migrating = self._fold_lapsed(fc.t0)
-            m.control_time_s += time.perf_counter() - t_ctl
+            with jax.profiler.TraceAnnotation(spans.CONTROL, chunk=m.chunks):
+                if adaptive or any(pl is None for pl in self.cur_plans):
+                    if adaptive:
+                        self._observe(fc)
+                    for p in range(self.k):
+                        self._replan_partition(
+                            p, self.estimator.snapshot(p), fc.t0, m)
+                migrating = self._fold_lapsed(fc.t0)
 
-            t_eng = time.perf_counter()
-            pre_fleet = self._active_fleet
-            state, (full, pm, ov, cl, ng) = self._plain_passes(
-                state, fc, fc.chunk, migrating)
-            # Overflow recovery: a truncated join may have dropped
-            # matches, so re-evaluate the window at the next pow2 capacity
-            # (events already ingested; the recount replaces the truncated
-            # one and the duplicate join work is charged to pm).
-            tries = 0
-            while (ov.sum() > 0 and self.escalate_on_overflow
-                   and tries < self.max_escalations):
-                self._active_fleet = self._escalated_fleet()
-                m.escalations += 1
-                tries += 1
-                empty = fc.chunk._replace(
-                    valid=jnp.zeros_like(fc.chunk.valid))
-                pm_so_far = pm
+            with jax.profiler.TraceAnnotation(spans.STEP, chunk=m.chunks):
+                pre_fleet = self._active_fleet
                 state, (full, pm, ov, cl, ng) = self._plain_passes(
-                    state, fc, empty, migrating)
-                pm = pm + pm_so_far
-            if migrating.any():
-                # A mid-migration overflow is the retiring plan's: recount
-                # at escalated capacity, but don't let the old era's shape
-                # outlive its migration window.
-                self._active_fleet = pre_fleet
-                m.migration_partition_chunks += int(migrating.sum())
-            m.engine_time_s += time.perf_counter() - t_eng
+                    state, fc, fc.chunk, migrating)
+                # Overflow recovery: a truncated join may have dropped
+                # matches, so re-evaluate the window at the next pow2
+                # capacity (events already ingested; the recount replaces
+                # the truncated one and the duplicate join work is charged
+                # to pm).
+                tries = 0
+                while (ov.sum() > 0 and self.escalate_on_overflow
+                       and tries < self.max_escalations):
+                    self._active_fleet = self._escalated_fleet()
+                    m.escalations += 1
+                    tries += 1
+                    empty = fc.chunk._replace(
+                        valid=jnp.zeros_like(fc.chunk.valid))
+                    pm_so_far = pm
+                    state, (full, pm, ov, cl, ng) = self._plain_passes(
+                        state, fc, empty, migrating)
+                    pm = pm + pm_so_far
+                if migrating.any():
+                    # A mid-migration overflow is the retiring plan's:
+                    # recount at escalated capacity, but don't let the old
+                    # era's shape outlive its migration window.
+                    self._active_fleet = pre_fleet
+                    m.migration_partition_chunks += int(migrating.sum())
 
             m.chunks += 1
             m.events += int(np.asarray(fc.chunk.valid).sum())
@@ -859,49 +856,51 @@ class MonitoredFleetRunner(FleetRunner):
             self._prime()
 
         for fc in fleet_stream:
-            t_ctl = time.perf_counter()
-            self._apply_pending(pending, rates_dev, sel_dev, fc.t0, m)
-            pending[:] = False
-            migrating = self._fold_lapsed(fc.t0)
-            m.control_time_s += time.perf_counter() - t_ctl
+            with jax.profiler.TraceAnnotation(spans.CONTROL, chunk=m.chunks):
+                self._apply_pending(pending, rates_dev, sel_dev, fc.t0, m)
+                pending[:] = False
+                migrating = self._fold_lapsed(fc.t0)
 
-            t_eng = time.perf_counter()
-            # Pass A, fused: joins + ring update + invariant verification
-            # in ONE compiled vmapped call.
-            state, monitor, res, violated, drift, rates_dev, sel_dev = \
-                self._active_fleet.process_chunk_monitored(
-                    state, monitor, fc.chunk, jnp.asarray(self._cur_rows),
-                    self._low.device(), fc.t0, fc.t1,
-                    born_lo=self._replan_t.astype(np.float32),
-                    born_hi=_POS_INF)
-            state, out = self._pass_b(state, fc, self._counters(res),
-                                      migrating, fc.chunk)
-            full, pm, ov, cl, ng = out
-            # Overflow-escalation recounts run the *plain* passes so the
-            # statistics ring is updated exactly once per chunk (by the
-            # monitored pass above) and flags are never double-observed.
-            pre_fleet = self._active_fleet
-            tries = 0
-            while (ov.sum() > 0 and self.escalate_on_overflow
-                   and tries < self.max_escalations):
-                self._active_fleet = self._escalated_fleet()
-                m.escalations += 1
-                tries += 1
-                empty = fc.chunk._replace(
-                    valid=jnp.zeros_like(fc.chunk.valid))
-                pm_so_far = pm
-                state, (full, pm, ov, cl, ng) = self._plain_passes(
-                    state, fc, empty, migrating)
-                pm = pm + pm_so_far
-            if migrating.any():
-                # Mid-migration overflow: transient recount, not a regime.
-                self._active_fleet = pre_fleet
-                m.migration_partition_chunks += int(migrating.sum())
+            with jax.profiler.TraceAnnotation(spans.STEP, chunk=m.chunks):
+                # Pass A, fused: joins + ring update + invariant
+                # verification in ONE compiled vmapped call.
+                state, monitor, res, violated, drift, rates_dev, sel_dev = \
+                    self._active_fleet.process_chunk_monitored(
+                        state, monitor, fc.chunk,
+                        jnp.asarray(self._cur_rows), self._low.device(),
+                        fc.t0, fc.t1,
+                        born_lo=self._replan_t.astype(np.float32),
+                        born_hi=_POS_INF)
+                state, out = self._pass_b(state, fc, self._counters(res),
+                                          migrating, fc.chunk)
+                full, pm, ov, cl, ng = out
+                # Overflow-escalation recounts run the *plain* passes so
+                # the statistics ring is updated exactly once per chunk (by
+                # the monitored pass above) and flags are never
+                # double-observed.
+                pre_fleet = self._active_fleet
+                tries = 0
+                while (ov.sum() > 0 and self.escalate_on_overflow
+                       and tries < self.max_escalations):
+                    self._active_fleet = self._escalated_fleet()
+                    m.escalations += 1
+                    tries += 1
+                    empty = fc.chunk._replace(
+                        valid=jnp.zeros_like(fc.chunk.valid))
+                    pm_so_far = pm
+                    state, (full, pm, ov, cl, ng) = self._plain_passes(
+                        state, fc, empty, migrating)
+                    pm = pm + pm_so_far
+                if migrating.any():
+                    # Mid-migration overflow: transient recount, not a
+                    # regime.
+                    self._active_fleet = pre_fleet
+                    m.migration_partition_chunks += int(migrating.sum())
 
-            # The entire per-chunk host round-trip: one (K,) bool vector.
-            pending = np.asarray(violated).copy()
-            m.last_drift = np.asarray(drift, np.float32)
-            m.engine_time_s += time.perf_counter() - t_eng
+                # The entire per-chunk host round-trip: one (K,) bool
+                # vector.
+                pending = np.asarray(violated).copy()
+                m.last_drift = np.asarray(drift, np.float32)
 
             m.chunks += 1
             m.events += int(np.asarray(fc.chunk.valid).sum())
@@ -948,108 +947,106 @@ class MonitoredFleetRunner(FleetRunner):
                     exhausted = True
             if not buf:
                 break
-            t_ctl = time.perf_counter()
-            self._apply_pending(pending, pend_rates, pend_sel,
-                                buf[0].t0, m)
-            pending[:] = False
-            n_en = len(buf)
-            ctl = window_control(self._replan_t, self._migration_until,
-                                 [fc.t0 for fc in buf], s_cap)
-            xs = stack_window([fc.chunk for fc in buf],
-                              [fc.t0 for fc in buf],
-                              [fc.t1 for fc in buf], ctl, s_cap)
-            cur_rows = jnp.asarray(self._cur_rows)
-            old_rows = jnp.asarray(self._old_rows)
-            m.control_time_s += time.perf_counter() - t_ctl
+            with jax.profiler.TraceAnnotation(spans.CONTROL, chunk=m.chunks):
+                self._apply_pending(pending, pend_rates, pend_sel,
+                                    buf[0].t0, m)
+                pending[:] = False
+                n_en = len(buf)
+                ctl = window_control(self._replan_t, self._migration_until,
+                                     [fc.t0 for fc in buf], s_cap)
+                xs = stack_window([fc.chunk for fc in buf],
+                                  [fc.t0 for fc in buf],
+                                  [fc.t1 for fc in buf], ctl, s_cap)
+                cur_rows = jnp.asarray(self._cur_rows)
+                old_rows = jnp.asarray(self._old_rows)
 
-            t_eng = time.perf_counter()
-            scan = self._active_fleet.superchunk_scan(monitored=True)
-            low_dev = self._low.device()
-            state2, monitor2, ys = scan(state, monitor, cur_rows, old_rows,
-                                        low_dev, xs)
-            # Eager readback is counters + flags + drift only; the (S, K,
-            # n[, n]) statistic stacks stay on device and are pulled
-            # per-partition at application time — host traffic stays
-            # O(violations), not O(S·K·stats), exactly as per-chunk.
-            (full_h, pm_h, ov_h, cl_h, ng_h, violated_h, drift_h) = \
-                jax.device_get((ys.full, ys.pm, ys.overflow, ys.closure,
-                                ys.neg, ys.violated, ys.drift))
-            f = first_event(violated_h, ov_h, n_en,
-                            self.escalate_on_overflow)
-            if f is not None and f < n_en - 1:
-                # In-window event: replay the prefix [0..f] from the saved
-                # pre-window carry (bitwise-identical compute) so the host
-                # can replan / escalate before chunk f+1 runs — exactly
-                # the per-chunk contract.  Costs one extra dispatch, only
-                # when an event actually fired.
-                en = np.zeros(s_cap, bool)
-                en[:f + 1] = True
-                xs_pre = xs._replace(enabled=jnp.asarray(en))
-                state2, monitor2, _ = scan(state, monitor, cur_rows,
-                                           old_rows, low_dev, xs_pre)
-            accept = n_en if f is None else f + 1
-            last = accept - 1
-            state, monitor = state2, monitor2
+            with jax.profiler.TraceAnnotation(spans.STEP, chunk=m.chunks):
+                scan = self._active_fleet.superchunk_scan(monitored=True)
+                low_dev = self._low.device()
+                state2, monitor2, ys = scan(state, monitor, cur_rows, old_rows,
+                                            low_dev, xs)
+                # Eager readback is counters + flags + drift only; the (S, K,
+                # n[, n]) statistic stacks stay on device and are pulled
+                # per-partition at application time — host traffic stays
+                # O(violations), not O(S·K·stats), exactly as per-chunk.
+                (full_h, pm_h, ov_h, cl_h, ng_h, violated_h, drift_h) = \
+                    jax.device_get((ys.full, ys.pm, ys.overflow, ys.closure,
+                                    ys.neg, ys.violated, ys.drift))
+                f = first_event(violated_h, ov_h, n_en,
+                                self.escalate_on_overflow)
+                if f is not None and f < n_en - 1:
+                    # In-window event: replay the prefix [0..f] from the saved
+                    # pre-window carry (bitwise-identical compute) so the host
+                    # can replan / escalate before chunk f+1 runs — exactly
+                    # the per-chunk contract.  Costs one extra dispatch, only
+                    # when an event actually fired.
+                    en = np.zeros(s_cap, bool)
+                    en[:f + 1] = True
+                    xs_pre = xs._replace(enabled=jnp.asarray(en))
+                    state2, monitor2, _ = scan(state, monitor, cur_rows,
+                                               old_rows, low_dev, xs_pre)
+                accept = n_en if f is None else f + 1
+                last = accept - 1
+                state, monitor = state2, monitor2
 
-            # Commit host mirrors to the fold state at the last accepted
-            # chunk (float64, same trajectory the per-chunk loop walks —
-            # including retiring the lapsed partitions' old plans).
-            self._replan_t = ctl.replan_seq[last].copy()
-            lapsed = ctl.old_sel[last]
-            self._old_rows[lapsed] = self._cur_rows[lapsed]
-            for p in np.nonzero(lapsed)[0]:
-                self.old_plans[p] = None
+                # Commit host mirrors to the fold state at the last accepted
+                # chunk (float64, same trajectory the per-chunk loop walks —
+                # including retiring the lapsed partitions' old plans).
+                self._replan_t = ctl.replan_seq[last].copy()
+                lapsed = ctl.old_sel[last]
+                self._old_rows[lapsed] = self._cur_rows[lapsed]
+                for p in np.nonzero(lapsed)[0]:
+                    self.old_plans[p] = None
 
-            counters = [np.asarray(c, np.int64)
-                        for c in (full_h, pm_h, ov_h, cl_h, ng_h)]
-            full_l, pm_l, ov_l, cl_l, ng_l = (c[last].copy()
-                                              for c in counters)
-            pre_fleet = self._active_fleet
-            if (self.escalate_on_overflow and ov_l.sum() > 0):
-                # Overflow recovery for the event chunk, identical to the
-                # per-chunk loop: re-evaluate at the next pow2 match
-                # capacity from the post-chunk state (events are already
-                # ingested); the escalated fleet persists for the
-                # following windows.
-                migrating_l = ctl.migrating[last]
-                tries = 0
-                while ov_l.sum() > 0 and tries < self.max_escalations:
-                    self._active_fleet = self._escalated_fleet()
-                    m.escalations += 1
-                    tries += 1
-                    empty = buf[last].chunk._replace(
-                        valid=jnp.zeros_like(buf[last].chunk.valid))
-                    pm_so_far = pm_l
-                    state, (full_l, pm_l, ov_l, cl_l, ng_l) = \
-                        self._plain_passes(state, buf[last], empty,
-                                           migrating_l)
-                    pm_l = pm_l + pm_so_far
-            if ctl.migrating[last].any():
-                # Mid-migration overflow: transient recount, not a regime
-                # (mirrors the per-chunk loop chunk-for-chunk).
-                self._active_fleet = pre_fleet
+                counters = [np.asarray(c, np.int64)
+                            for c in (full_h, pm_h, ov_h, cl_h, ng_h)]
+                full_l, pm_l, ov_l, cl_l, ng_l = (c[last].copy()
+                                                  for c in counters)
+                pre_fleet = self._active_fleet
+                if (self.escalate_on_overflow and ov_l.sum() > 0):
+                    # Overflow recovery for the event chunk, identical to the
+                    # per-chunk loop: re-evaluate at the next pow2 match
+                    # capacity from the post-chunk state (events are already
+                    # ingested); the escalated fleet persists for the
+                    # following windows.
+                    migrating_l = ctl.migrating[last]
+                    tries = 0
+                    while ov_l.sum() > 0 and tries < self.max_escalations:
+                        self._active_fleet = self._escalated_fleet()
+                        m.escalations += 1
+                        tries += 1
+                        empty = buf[last].chunk._replace(
+                            valid=jnp.zeros_like(buf[last].chunk.valid))
+                        pm_so_far = pm_l
+                        state, (full_l, pm_l, ov_l, cl_l, ng_l) = \
+                            self._plain_passes(state, buf[last], empty,
+                                               migrating_l)
+                        pm_l = pm_l + pm_so_far
+                if ctl.migrating[last].any():
+                    # Mid-migration overflow: transient recount, not a regime
+                    # (mirrors the per-chunk loop chunk-for-chunk).
+                    self._active_fleet = pre_fleet
 
-            for s in range(accept):
-                m.chunks += 1
-                m.events += int(np.asarray(buf[s].chunk.valid).sum())
-                row = ((full_l, pm_l, ov_l, cl_l, ng_l) if s == last
-                       else tuple(c[s] for c in counters))
-                full, pm, ov, cl, ng = row
-                m.full_matches += int(full.sum())
-                m.pm_created += int(pm.sum())
-                m.overflow += int(ov.sum())
-                m.closure_expansions += int(cl.sum())
-                m.neg_rejected += int(ng.sum())
-                m.per_partition_matches += np.asarray(full, np.int64)
-            m.migration_partition_chunks += int(
-                ctl.migrating[:accept].sum())
-            m.last_drift = np.asarray(drift_h[last], np.float32)
-            pending = np.asarray(violated_h[last]).copy()
-            # Device slices: _apply_pending materializes row p only for
-            # partitions whose flag actually fired.
-            pend_rates = ys.rates[last]
-            pend_sel = ys.sel[last]
-            m.engine_time_s += time.perf_counter() - t_eng
+                for s in range(accept):
+                    m.chunks += 1
+                    m.events += int(np.asarray(buf[s].chunk.valid).sum())
+                    row = ((full_l, pm_l, ov_l, cl_l, ng_l) if s == last
+                           else tuple(c[s] for c in counters))
+                    full, pm, ov, cl, ng = row
+                    m.full_matches += int(full.sum())
+                    m.pm_created += int(pm.sum())
+                    m.overflow += int(ov.sum())
+                    m.closure_expansions += int(cl.sum())
+                    m.neg_rejected += int(ng.sum())
+                    m.per_partition_matches += np.asarray(full, np.int64)
+                m.migration_partition_chunks += int(
+                    ctl.migrating[:accept].sum())
+                m.last_drift = np.asarray(drift_h[last], np.float32)
+                pending = np.asarray(violated_h[last]).copy()
+                # Device slices: _apply_pending materializes row p only for
+                # partitions whose flag actually fired.
+                pend_rates = ys.rates[last]
+                pend_sel = ys.sel[last]
             buf = buf[accept:]
         self._save_carry(state, monitor, pending, pend_rates, pend_sel)
         return m

@@ -24,6 +24,7 @@ not O(K·stats).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Dict, Optional, Tuple
 
@@ -31,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import spans
 from ..core.adaptation import make_planner
 from ..core.decision import InvariantPolicy
 from ..core.engine import EngineConfig
@@ -110,6 +112,10 @@ class CEPFleetServingEngine:
     ``(t0, t1]``, routes it to partitions and advances all K partitions in
     one compiled call.  Per-partition cumulative match counts and
     capacity-drop back-pressure are exposed for the scheduler.
+
+    Each tick writes the host spans of ``core.spans`` with the tick index
+    ``ticks`` as argument; ``readbacks`` counts its blocking device→host
+    reads.
     """
 
     def __init__(self, pattern: Pattern, k: int, plans,
@@ -136,6 +142,8 @@ class CEPFleetServingEngine:
         self.closure_expansions = np.zeros(k, np.int64)
         self.overflow = np.zeros(k, np.int64)
         self.dropped = 0
+        self.ticks = 0
+        self.readbacks = 0
 
     def reset(self) -> None:
         """Clear stream state and counters; compiled programs and deployed
@@ -145,6 +153,8 @@ class CEPFleetServingEngine:
                     self.closure_expansions, self.overflow):
             arr[:] = 0
         self.dropped = 0
+        self.ticks = 0
+        self.readbacks = 0
 
     def deploy_plan(self, partition: int, plan) -> None:
         """Cheap deployment (§2.2): rewrite one stacked plan row."""
@@ -157,20 +167,30 @@ class CEPFleetServingEngine:
         engine-side drop channel; the router's ``late_dropped`` is the
         only other one, so ``submitted == reached-engine + late_dropped +
         dropped + pending`` is checkable end to end."""
-        chunk, dropped = route_events(
-            np.asarray(type_id), np.asarray(ts), np.asarray(attr),
-            np.asarray(keys), self.k, self.chunk_cap)
+        with jax.profiler.TraceAnnotation(spans.ROUTE, chunk=self.ticks):
+            chunk, dropped = route_events(
+                np.asarray(type_id), np.asarray(ts), np.asarray(attr),
+                np.asarray(keys), self.k, self.chunk_cap)
         self.dropped += dropped
         return chunk
+
+    @contextlib.contextmanager
+    def _readback(self):
+        """One blocking device→host read: a ``cep.readback`` span and a
+        count in ``readbacks``."""
+        with jax.profiler.TraceAnnotation(spans.READBACK, chunk=self.ticks):
+            yield
+        self.readbacks += 1
 
     def _accumulate(self, res) -> np.ndarray:
         # One device→host transfer for all four counters: per-array
         # fetches cost a dispatch + transfer each and dominate the serving
         # tick at small chunk sizes (the facade-overhead budget in
         # benchmarks/fleet_bench.py watches this path).
-        full, neg, clo, ov = np.asarray(jnp.stack(
-            [res.full_matches, res.neg_rejected, res.closure_expansions,
-             res.overflow]), np.int64)
+        with self._readback():
+            full, neg, clo, ov = np.asarray(jnp.stack(
+                [res.full_matches, res.neg_rejected,
+                 res.closure_expansions, res.overflow]), np.int64)
         self.matches += full
         self.neg_rejected += neg
         self.closure_expansions += clo
@@ -181,9 +201,12 @@ class CEPFleetServingEngine:
 
     def process_chunk(self, chunk, t0: float, t1: float) -> np.ndarray:
         """Tick the fleet once over an already-routed stacked chunk."""
-        self.state, res = self.fleet.process_chunk(
-            self.state, chunk, self._rows, t0, t1)
-        return self._accumulate(res)
+        with jax.profiler.TraceAnnotation(spans.STEP, chunk=self.ticks):
+            self.state, res = self.fleet.process_chunk(
+                self.state, chunk, self._rows, t0, t1)
+        full = self._accumulate(res)
+        self.ticks += 1
+        return full
 
     def process_batch(self, type_id, ts, attr, keys,
                       t0: float, t1: float) -> np.ndarray:
@@ -238,10 +261,12 @@ class CEPFleetServingEngine:
             rows = jnp.asarray(self._rows)
             self.state, _, ys = scan(self.state, None, rows, rows,
                                      None, xs)
-            ys_h = jax.device_get((ys.full, ys.neg, ys.closure,
-                                   ys.overflow))
+            with self._readback():
+                ys_h = jax.device_get((ys.full, ys.neg, ys.closure,
+                                       ys.overflow))
             out[i:i + len(win)] = self._accumulate_rows(ys_h, len(win))
             i += len(win)
+            self.ticks += len(win)
         return out
 
 
@@ -322,33 +347,47 @@ class MonitoredCEPFleetServingEngine(CEPFleetServingEngine):
         """The O(violations) control plane: sync + replan flagged rows only.
 
         ``rates``/``sel`` may be device or host arrays; a partition's
-        snapshot is materialized only when its flag fired.
+        snapshot is materialized only when its flag fired.  One
+        ``cep.control`` span on every tick, one ``cep.replan`` inside it
+        per flagged partition.
         """
-        for p in np.nonzero(np.asarray(fired_mask))[0]:
-            self.violations[p] += 1
-            self.host_syncs += 1
-            stat = Stat(np.asarray(rates[p], np.float64),
-                        np.asarray(sel[p], np.float64))
-            new_plan = replan_flagged_partition(
-                self.pattern, self.planner, self.policies[p],
-                self._low, p, stat, self._caps)
-            if new_plan != self.plans[p]:
-                self.deploy_plan(p, new_plan)  # also records self.plans[p]
-                self.replans[p] += 1
+        with jax.profiler.TraceAnnotation(spans.CONTROL, chunk=self.ticks):
+            for p in np.nonzero(np.asarray(fired_mask))[0]:
+                with jax.profiler.TraceAnnotation(
+                        spans.REPLAN, chunk=self.ticks, partition=int(p)):
+                    self._replan(int(p), rates, sel)
+
+    def _replan(self, p: int, rates, sel) -> None:
+        self.violations[p] += 1
+        self.host_syncs += 1
+        with self._readback():
+            rates_p = np.asarray(rates[p], np.float64)
+        with self._readback():
+            sel_p = np.asarray(sel[p], np.float64)
+        new_plan = replan_flagged_partition(
+            self.pattern, self.planner, self.policies[p],
+            self._low, p, Stat(rates_p, sel_p), self._caps)
+        if new_plan != self.plans[p]:
+            self.deploy_plan(p, new_plan)  # also records self.plans[p]
+            self.replans[p] += 1
 
     def process_chunk(self, chunk, t0: float, t1: float) -> np.ndarray:
         """Tick the fused monitored fleet over an already-routed chunk and
         replan any partition whose invariant flag fired."""
-        self.state, self.monitor, res, violated, drift, rates, sel = \
-            self.fleet.process_chunk_monitored(
-                self.state, self.monitor, chunk, self._rows,
-                self._low.device(), t0, t1)
+        with jax.profiler.TraceAnnotation(spans.STEP, chunk=self.ticks):
+            self.state, self.monitor, res, violated, drift, rates, sel = \
+                self.fleet.process_chunk_monitored(
+                    self.state, self.monitor, chunk, self._rows,
+                    self._low.device(), t0, t1)
         full = self._accumulate(res)
         # Coalesce the flag + drift readback into one transfer (the only
         # extra per-tick host traffic device monitoring costs).
-        vd = np.asarray(jnp.stack([violated.astype(jnp.float32), drift]))
+        with self._readback():
+            vd = np.asarray(jnp.stack([violated.astype(jnp.float32),
+                                       drift]))
         self.last_drift = vd[1].astype(np.float32)
         self._apply_flags(vd[0] > 0.5, rates, sel)
+        self.ticks += 1
         return full
 
     def process_superchunk(self, chunks, edges) -> np.ndarray:
@@ -386,9 +425,10 @@ class MonitoredCEPFleetServingEngine(CEPFleetServingEngine):
             # stacks stay device-resident and are materialized
             # per-partition only when a flag fired (O(violations) host
             # traffic, as in the per-tick path).
-            ys_h = jax.device_get(
-                (ys.full, ys.pm, ys.overflow, ys.closure, ys.neg,
-                 ys.violated, ys.drift))
+            with self._readback():
+                ys_h = jax.device_get(
+                    (ys.full, ys.pm, ys.overflow, ys.closure, ys.neg,
+                     ys.violated, ys.drift))
             (full_h, pm_h, ov_h, cl_h, ng_h, violated_h, drift_h) = ys_h
             f = first_event(violated_h, ov_h, n_en, escalate=False)
             if f is not None and f < n_en - 1:
@@ -406,4 +446,5 @@ class MonitoredCEPFleetServingEngine(CEPFleetServingEngine):
             self._apply_flags(violated_h[last], ys.rates[last],
                               ys.sel[last])
             i += accept
+            self.ticks += accept
         return out
