@@ -67,6 +67,10 @@ _NONE = PRED_NONE
 NEG_INF = -3.0e38
 POS_INF = 3.0e38
 
+# Width of the column blocks the compaction counts survivors in: one lane
+# row of the TPU's vector registers.
+_BLOCK = 128
+
 
 class Chunk(NamedTuple):
     """One stream chunk (struct-of-arrays)."""
@@ -160,26 +164,83 @@ def _pred_rows(spec, L: MatchSet, R: MatchSet):
     return rows
 
 
+def _rank_level(running, ranks):
+    """Per rank ``r``: how many of its ``running`` counts lie below ``r``,
+    and ``r`` less the last of them (its rank within the next entry).
+
+    ``running`` is nondecreasing along its last axis and broadcasts against
+    ``ranks[:, None]``; the search is one dense compare and reduction."""
+    below = running < ranks[:, None]
+    return (below.sum(axis=1, dtype=jnp.int32),
+            ranks - jnp.max(jnp.where(below, running, 0), axis=1))
+
+
+def _select(ok, out_cap: int):
+    """Flat indices of the first ``out_cap`` true entries of ``ok``
+    ``(m, b)`` in row-major order, ``m * b`` past the last of them.
+
+    A rank search in three levels, by gathers and dense compares alone: the
+    row of the r-th survivor from the rows' running counts, its 128-wide
+    block within the row from the row's running block counts, and its
+    column from a prefix sum over that one gathered block: no scatter, and
+    no prefix sum over the whole mask."""
+    m, b = ok.shape
+    w = min(b, _BLOCK)
+    nb = -(-b // w)
+    if nb * w != b:
+        ok = jnp.pad(ok, ((0, 0), (0, nb * w - b)))
+    blocks = ok.reshape(m * nb, w)
+    block_run = jnp.cumsum(
+        blocks.sum(axis=1, dtype=jnp.int32).reshape(m, nb), axis=1)
+    row, r = _rank_level(jnp.cumsum(block_run[:, -1]),
+                         jnp.arange(1, out_cap + 1, dtype=jnp.int32))
+    rowc = jnp.minimum(row, m - 1)
+    blk = jnp.zeros_like(row)
+    if nb > 1:
+        blk, r = _rank_level(block_run[rowc], r)
+        blk = jnp.minimum(blk, nb - 1)
+    col, _ = _rank_level(
+        jnp.cumsum(blocks[rowc * nb + blk], axis=1, dtype=jnp.int32), r)
+    return jnp.where(row < m, rowc * b + blk * w + col, m * b)
+
+
+def _gather_rows(S: MatchSet, i):
+    """``ts``, ``attr``, ``min_ts`` and ``max_ts`` of the rows ``i`` of
+    ``S``, by one gather of the fields packed side by side."""
+    rows, n = S.ts.shape
+    packed = jnp.concatenate(
+        [S.ts, S.attr.reshape(rows, -1), S.min_ts[:, None],
+         S.max_ts[:, None]], axis=1)[i]
+    return (packed[:, :n],
+            packed[:, n:-2].reshape(i.shape + S.attr.shape[1:]),
+            packed[:, -2], packed[:, -1])
+
+
 @jax.named_scope(spans.COMPACT)
 def _compact(L: MatchSet, R: MatchSet, ok, pm_created, out_cap: int):
-    """Prefix-sum compaction of the surviving (m, b) pairs into a MatchSet."""
+    """Compaction of the surviving (m, b) pairs into a MatchSet.
+
+    The r-th output row is the r-th true pair of ``ok`` in row-major order;
+    rows past the survivors get the index ``m * b`` and come out invalid.
+    This is what ``jnp.nonzero(flat, size=out_cap, fill_value=m * b)``
+    selects, found by ``_select``'s rank search instead of ``nonzero``'s
+    scatter of every mask element into ``out_cap`` bins, which a TPU runs
+    serially."""
     m = L.valid.shape[0]
     b = R.valid.shape[0]
-    flat = ok.reshape(-1)
-    idx = jnp.nonzero(flat, size=out_cap, fill_value=m * b)[0]
-    new_valid = jnp.take(flat, idx, mode="fill", fill_value=False)
+    idx = _select(ok, out_cap)
     mi = jnp.clip(idx // b, 0, m - 1)
     bi = jnp.clip(idx % b, 0, b - 1)
 
+    l_ts, l_attr, l_min, l_max = _gather_rows(L, mi)
+    r_ts, r_attr, r_min, r_max = _gather_rows(R, bi)
     memL = L.member[None, :]
-    ts = jnp.where(memL, L.ts[mi], R.ts[bi])
-    attr = jnp.where(memL[:, :, None], L.attr[mi], R.attr[bi])
     out = MatchSet(
-        ts=ts,
-        attr=attr,
-        min_ts=jnp.minimum(L.min_ts[mi], R.min_ts[bi]),
-        max_ts=jnp.maximum(L.max_ts[mi], R.max_ts[bi]),
-        valid=new_valid,
+        ts=jnp.where(memL, l_ts, r_ts),
+        attr=jnp.where(memL[:, :, None], l_attr, r_attr),
+        min_ts=jnp.minimum(l_min, r_min),
+        max_ts=jnp.maximum(l_max, r_max),
+        valid=idx < m * b,
         member=L.member | R.member,
     )
     overflow = jnp.maximum(0, pm_created - out_cap).astype(jnp.int32)

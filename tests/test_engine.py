@@ -1,11 +1,13 @@
 """CEP engine vs the brute-force oracle (``core.ref_engine``) over all
 operators and both plan families, plus chunked exactly-once counting."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.engine import (Chunk, EngineConfig, OrderEngine, TreeEngine)
+from repro.core.engine import (Chunk, EngineConfig, MatchSet, OrderEngine,
+                               TreeEngine, _compact)
 from repro.core.patterns import (
     PRED_ABS_LE, PRED_LT, Predicate, and_pattern, chain_predicates,
     kleene_pattern, neg_pattern, seq_pattern,
@@ -165,3 +167,94 @@ def test_pm_created_tracks_plan_quality(rng):
         0.0, 200.0)
     assert int(good.full_matches) == int(bad.full_matches)
     assert int(good.pm_created) < int(bad.pm_created)
+
+
+# Compaction: the rank search selects what jnp.nonzero(size=) selects.
+
+CAP = 64
+# Order steps join match sets with one block of events; tree steps join two
+# match sets, whose rows span several 128-wide blocks (here padded to 3).
+COMPACT_SHAPES = {"order": (32, 128), "tree": (300, 300), "blocks": (24, 256)}
+FILLS = {"empty": 0, "below": CAP // 2, "at": CAP, "above": 3 * CAP,
+         "full": None}
+
+
+def _nonzero_compact(L, R, ok, pm_created, out_cap):
+    """The compaction as ``jnp.nonzero(size=, fill_value=)`` selects it."""
+    m, b = ok.shape
+    flat = ok.reshape(-1)
+    idx = jnp.nonzero(flat, size=out_cap, fill_value=m * b)[0]
+    mi = jnp.clip(idx // b, 0, m - 1)
+    bi = jnp.clip(idx % b, 0, b - 1)
+    memL = L.member[None, :]
+    out = MatchSet(
+        ts=jnp.where(memL, L.ts[mi], R.ts[bi]),
+        attr=jnp.where(memL[:, :, None], L.attr[mi], R.attr[bi]),
+        min_ts=jnp.minimum(L.min_ts[mi], R.min_ts[bi]),
+        max_ts=jnp.maximum(L.max_ts[mi], R.max_ts[bi]),
+        valid=jnp.take(flat, idx, mode="fill", fill_value=False),
+        member=L.member | R.member,
+    )
+    return out, jnp.maximum(0, pm_created - out_cap).astype(jnp.int32)
+
+
+def _match_set(rng, rows, member):
+    ts = rng.uniform(0, 10, (rows, 3)).astype(np.float32)
+    return MatchSet(
+        ts=jnp.asarray(ts),
+        attr=jnp.asarray(rng.normal(size=(rows, 3, 2)).astype(np.float32)),
+        min_ts=jnp.asarray(ts.min(axis=1)),
+        max_ts=jnp.asarray(ts.max(axis=1)),
+        valid=jnp.asarray(rng.random(rows) < 0.8),
+        member=jnp.asarray(member))
+
+
+def _mask(rng, m, b, n_true):
+    ok = np.zeros(m * b, bool)
+    n_true = m * b if n_true is None else n_true
+    ok[rng.choice(m * b, n_true, replace=False)] = True
+    return ok.reshape(m, b)
+
+
+def _operands(rng, kind, fill):
+    m, b = COMPACT_SHAPES[kind]
+    L = _match_set(rng, m, [True, False, True])
+    R = _match_set(rng, b, [False, True, False])
+    ok = jnp.asarray(_mask(rng, m, b, FILLS[fill]))
+    return L, R, ok, ok.sum().astype(jnp.int32)
+
+
+def _assert_same(got, want):
+    (out, created, overflow), (ref, ref_overflow) = got, want
+    for field in MatchSet._fields:
+        np.testing.assert_array_equal(getattr(out, field),
+                                      getattr(ref, field), err_msg=field)
+    assert int(overflow) == int(ref_overflow)
+
+
+@pytest.mark.parametrize("fill", list(FILLS))
+@pytest.mark.parametrize("kind", list(COMPACT_SHAPES))
+def test_compact_selects_as_nonzero(kind, fill, rng):
+    L, R, ok, created = _operands(rng, kind, fill)
+    got = jax.jit(_compact, static_argnums=4)(L, R, ok, created, CAP)
+    _assert_same(got, _nonzero_compact(L, R, ok, created, CAP))
+    # The first CAP true pairs in row-major order, in order; then padding.
+    out = got[0]
+    b = ok.shape[1]
+    pos = np.flatnonzero(np.asarray(ok))[:CAP]
+    n = len(pos)
+    assert np.asarray(out.valid).tolist() == [True] * n + [False] * (CAP - n)
+    np.testing.assert_array_equal(out.ts[:n, 0], L.ts[pos // b, 0])
+    np.testing.assert_array_equal(out.ts[:n, 1], R.ts[pos % b, 1])
+    assert int(got[2]) == max(0, int(created) - CAP)
+
+
+@pytest.mark.parametrize("kind", list(COMPACT_SHAPES))
+def test_compact_selects_as_nonzero_under_vmap(kind, rng):
+    parts = [_operands(rng, kind, fill) for fill in FILLS]
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *parts)
+    got = jax.jit(jax.vmap(_compact, in_axes=(0, 0, 0, 0, None)),
+                  static_argnums=4)(*stacked, CAP)
+    for k, part in enumerate(parts):
+        _assert_same(jax.tree.map(lambda x: x[k], got),
+                     _nonzero_compact(*part, CAP))

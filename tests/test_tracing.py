@@ -6,6 +6,7 @@ the lowered step's debug info, where XLA takes its ``op_name`` metadata.
 """
 
 import glob
+import re
 from collections import Counter
 
 import jax
@@ -141,3 +142,16 @@ def test_compiled_step_names_its_parts(kind):
     for name in (spans.INGEST, spans.JOIN, spans.COMPACT, spans.FINALIZE,
                  spans.MONITOR, spans.VERIFY):
         assert f"({name})/" in text, name
+
+
+@pytest.mark.parametrize("kind", ["order", "tree"])
+def test_compaction_lowers_without_a_scatter(kind):
+    """The compaction selects by a gather-only rank search; a scatter under
+    ``cep.compact`` (as ``jnp.nonzero(size=)`` lowers to) runs serially
+    over every mask element on a TPU."""
+    text = _lowered_step(kind)
+    scopes = set()
+    for name in re.findall(r'loc\("([^"]*/scatter[^"/]*)"', text):
+        scopes.update(re.findall(r"cep\.\w+", name))
+    assert spans.INGEST in scopes  # the buffers' writes are scatters
+    assert spans.COMPACT not in scopes
