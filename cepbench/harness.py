@@ -2,15 +2,23 @@
 reduction and the check against the plain reference.
 
 A cell names a configuration and a traffic mix.  Both are files found by
-name (``configs/<config>.json``, ``traffic/<mix>.json``); the pattern
-kind of the configuration names its program-side builder
-(``patterns/<kind>.py``) and its plain reference
-(``references/<kind>.py``); each per-layer metric is read by
-``metrics/<metric>.py``.  Adding any of them adds files and edits none.
+name (``configs/<config>.json``, ``traffic/<mix>.json``).  The
+configuration names the served system it opens (``systems/<system>.py``
+under its key ``system``, ``session`` where it has none) and its pattern
+kind, whose program-side builder is ``patterns/<kind>.py`` and whose
+plain reference is ``references/<kind>.py``; each per-layer metric is
+read by ``metrics/<metric>.py``.  Adding any of them adds files and edits
+none.
 
-The system under test is the served session of the program,
-``cep.open(pattern, partitions=K, plan=..., monitor=True).process(...)``,
-one call per slice.  A drive mode feeds it:
+A system file exports ``open(config, here)``, which returns an object
+with ``process(type_id, ts, attr, keys, t0, t1)`` and ``counters()``
+(``replans``, ``violations``, ``overflow``, ``dropped``).  ``process``
+answers one slice: one count per partition, ``(K,)``, or one per
+partition and rule, ``(K, R)``, rules in the configuration's order; the
+reference answers a partition's slice with a count or an ``(R,)`` vector
+to match.  The ``session`` system is the program's served session,
+``cep.open(pattern, partitions=K, plan=..., monitor=True).process(...)``.
+A drive mode feeds the system one call per slice:
 
 * ``replay``: the slices of a pool generated from the seed in set-up, back
   to back; past the pool's end the pool repeats, moved forward by whole
@@ -97,39 +105,48 @@ def load_cell(root: str, name: str) -> SimpleNamespace:
 # ---------------------------------------------------------------------------
 
 
-class Program:
-    """The program's served session, as a deployment opens it."""
+DEFAULT_SYSTEM = "session"
 
-    def __init__(self, config: dict, here: str):
-        from repro import cep
-        from repro.cep import RuntimeConfig
 
-        spec = config["pattern"]
-        build = _module(os.path.join(here, "patterns",
-                                     spec["kind"] + ".py")).build
-        self.session = cep.open(
-            build(spec), partitions=int(config["partitions"]),
-            plan=config["plan"], monitor=True,
-            config=RuntimeConfig(**config["runtime"]))
+def _system_module(here: str, name: str):
+    folder = os.path.join(here, "systems")
+    known = sorted(f[:-3] for f in os.listdir(folder) if f.endswith(".py"))
+    if name not in known:
+        raise SystemExit(f"cepbench: unknown system {name!r}; known: "
+                         f"{known}")
+    return _module(os.path.join(folder, name + ".py"))
 
-    def process(self, type_id, ts, attr, keys, t0, t1) -> np.ndarray:
-        return self.session.process(type_id, ts, attr, keys, t0, t1)
 
-    def counters(self) -> Dict[str, int]:
-        tel = self.session.telemetry()
-        return {"replans": tel.replans, "violations": tel.violations,
-                "overflow": tel.overflow, "dropped": tel.dropped}
+def open_system(config: dict, here: str):
+    """The served system that ``config`` names, opened for one run."""
+    return _system_module(here, config.get("system", DEFAULT_SYSTEM)) \
+        .open(config, here)
+
+
+def __getattr__(name: str):
+    # ``Program``: the session system's class, for tests that plant
+    # faults in it.
+    if name == "Program":
+        here = os.path.dirname(os.path.abspath(__file__))
+        return _system_module(here, DEFAULT_SYSTEM).Program
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
+def reference(here: str, spec: dict):
+    """The plain reference of the pattern kind of ``spec``."""
+    return _module(os.path.join(here, "references",
+                                spec["kind"] + ".py")).Reference
 
 
 class Control:
     """The plain reference in the program's place, with its guarantee
     broken: each slice is matched without the slices before it, so
-    matches that span a slice edge are lost."""
+    matches that span a slice edge are lost.  Answers ``(K,)`` or
+    ``(K, R)``, as the reference answers a count or an ``(R,)`` vector."""
 
     def __init__(self, config: dict, here: str):
         spec = config["pattern"]
-        ref = _module(os.path.join(here, "references",
-                                   spec["kind"] + ".py")).Reference
+        ref = reference(here, spec)
         self.k = int(config["partitions"])
         self.refs = [ref(spec, carry=False) for _ in range(self.k)]
 
@@ -248,34 +265,46 @@ def pool_slices(mix: dict, seconds: float) -> int:
 # ---------------------------------------------------------------------------
 
 
+def count_mismatches(got: np.ndarray, want: np.ndarray) -> int:
+    """The entries of ``want`` that ``got`` does not match: all of them
+    where the shapes differ."""
+    if got.shape != want.shape:
+        return int(want.size)
+    return int(np.count_nonzero(got != want))
+
+
 def check(cell, pool, rows, counters: dict, unanswered: int):
-    """Every answered slice's per-partition count against the reference.
+    """Every answered slice's counts against the reference, entry by
+    entry: one per partition, or one per partition and rule.
 
     Returns ``(compared, attempted, failed)``: each number compared with
-    its limit, the (slice, partition) answers checked, and those that
-    differ from the reference.
+    its limit, the (slice, partition, rule) entries checked, and those
+    that differ from the reference or belong to an unanswered slice.
     """
     spec = cell.config["pattern"]
-    ref = _module(os.path.join(cell.here, "references",
-                               spec["kind"] + ".py")).Reference
+    ref = reference(cell.here, spec)
     k = int(cell.config["partitions"])
     refs = [ref(spec) for _ in range(k)]
     mismatches = 0
+    entries = k  # per slice: K x R, R from the reference's answers
     for i, (s, t0, t1, counts, *_rest) in enumerate(rows):
         if s != i:
             raise RuntimeError(f"slice {s} processed out of order")
+        want = []
         for p in range(k):
             x = streams.partition_slice(pool, s, p)
-            want = refs[p].process(x.type_id, x.ts, x.attr, t0, t1)
-            mismatches += int(counts[p] != want)
+            want.append(refs[p].process(x.type_id, x.ts, x.attr, t0, t1))
+        want = np.array(want, np.int64)
+        entries = want.size
+        mismatches += count_mismatches(counts, want)
     compared = {
         "count_mismatches": {"value": mismatches, "limit": 0},
         "dropped_events": {"value": int(counters["dropped"]), "limit": 0},
         "overflow": {"value": int(counters["overflow"]), "limit": 0},
         "unanswered_slices": {"value": int(unanswered), "limit": 0},
     }
-    failed = mismatches + k * int(unanswered)
-    return compared, len(rows) * k, failed
+    failed = mismatches + entries * int(unanswered)
+    return compared, len(rows) * entries, failed
 
 
 # ---------------------------------------------------------------------------
@@ -345,9 +374,10 @@ def run_cell(root: str, name: str, seed: int, seconds: float,
              patch: Optional[dict] = None) -> dict:
     """One run of cell ``name``; returns the result line as a dict.
 
-    ``system_factory(config, here)`` puts another system in the program's
-    place, and ``patch`` overrides keys of the configuration and the mix
-    (``{"config": {...}, "mix": {...}}``); both serve the tests.  Without
+    ``system_factory(config, here)`` puts another system in place of the
+    one the configuration names, and ``patch`` overrides keys of the
+    configuration and the mix (``{"config": {...}, "mix": {...}}``); both
+    serve the tests.  Without
     ``require_tpu`` (a rehearsal on the CPU, whose trace holds no device
     ops) a per-layer metric may read nothing.
     """
@@ -368,7 +398,7 @@ def run_cell(root: str, name: str, seed: int, seconds: float,
     jax.monitoring.register_event_duration_secs_listener(compiles)
     try:
         return _run(cell, seed, seconds, traced, t_start,
-                    system_factory or Program, devices, peak, compiles,
+                    system_factory or open_system, devices, peak, compiles,
                     strict=require_tpu)
     finally:
         jax.monitoring.unregister_event_duration_listener(compiles)

@@ -1,6 +1,7 @@
 """CPU rehearsal of the harness at a tiny size: both drive modes, the
 result line, the check against the reference, its control and planted
-faults, and the refusal to measure without a TPU.
+faults, a served system of two rules added as files only, and the refusal
+to measure without a TPU.
 
 The rehearsal calls ``harness.run_cell`` with ``require_tpu=False``; the
 command itself (``run.py``) always requires the chip.
@@ -46,7 +47,8 @@ def test_replay_run_is_correct_and_well_formed():
                              "device"]
     assert list(out)[-1] == "compared"
     assert out["correct"] is True and out["failed"] == 0
-    assert out["attempted"] % 4 == 0 and out["attempted"] > 0
+    # One entry per answered slice (warm-up included) and partition.
+    assert out["attempted"] == (out["window"]["slices"] + 2) * 4
     assert set(out["metrics"]) == {"events_per_s", "setup_s"}
     assert all(m["value"] > 0 for m in out["metrics"].values())
     assert set(out["device"]) >= {"platform", "kind", "count",
@@ -123,6 +125,118 @@ class StateUnchanged(harness.Program):
                                    StateUnchanged])
 def test_planted_fault_is_not_correct(fault):
     out = run(factory=fault)
+    assert out["correct"] is False
+    assert out["compared"]["count_mismatches"]["value"] > 0
+
+
+def test_unknown_system_fails_setup():
+    with pytest.raises(SystemExit, match="known: .*'session'"):
+        harness.run_cell(ROOT, REPLAY, SEED, 1.0, False,
+                         t_start=time.perf_counter(), require_tpu=False,
+                         patch={"config": dict(TINY["config"],
+                                               system="no_such_system"),
+                                "mix": TINY["mix"]})
+
+
+def test_count_mismatches_entry_by_entry():
+    want = np.array([[1, 2], [3, 4], [5, 6]])
+    assert harness.count_mismatches(want.copy(), want) == 0
+    got = want.copy()
+    got[1, 0] += 1
+    assert harness.count_mismatches(got, want) == 1
+    assert harness.count_mismatches(want.T, want) == 6
+    assert harness.count_mismatches(want[:, 0], want) == 6
+    assert harness.count_mismatches(np.array([1, 2, 4]),
+                                    np.array([1, 2, 3])) == 1
+
+
+# A served system of two rules, added as files only: a configuration, a
+# system, a pattern kind and its reference (``two_rules/``), and a cell
+# entry, in a copy of the benchmark.
+RULES = "two_rules.traffic_replay"
+
+
+@pytest.fixture(scope="module")
+def rules_root(tmp_path_factory):
+    root = tmp_path_factory.mktemp("rules")
+    here = root / "cepbench"
+    shutil.copytree(os.path.join(ROOT, "cepbench"), here,
+                    ignore=shutil.ignore_patterns(".jax_cache", ".traces",
+                                                  "__pycache__", "tests"))
+    fixture = os.path.join(os.path.dirname(__file__), "two_rules")
+    for folder in os.listdir(fixture):
+        for name in os.listdir(os.path.join(fixture, folder)):
+            shutil.copy(os.path.join(fixture, folder, name), here / folder)
+    bench = harness._load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    bench["configs"].append({"name": "two_rules", "source": "test",
+                             "file": "cepbench/configs/two_rules.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": RULES, "config": "two_rules",
+                               "traffic": "traffic_replay", "chips": 1,
+                               "why": "test"})
+    for m in bench["end_to_end"]:
+        if m["name"] == "events_per_s":
+            m["workloads"].append(RULES)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return str(root)
+
+
+def run_rules(root, factory=None):
+    return harness.run_cell(root, RULES, SEED, 1.0, False,
+                            t_start=time.perf_counter(),
+                            system_factory=factory, require_tpu=False,
+                            patch={"config": {"runtime": TINY["config"][
+                                "runtime"]}, "mix": TINY["mix"]})
+
+
+def _answers(change):
+    """The configuration's own system, with ``change(call, out)`` applied
+    to each answer."""
+
+    def factory(config, here):
+        system = harness.open_system(config, here)
+        inner = system.process
+        calls = []
+
+        def process(*args):
+            calls.append(None)
+            return change(len(calls), np.array(inner(*args)))
+
+        system.process = process
+        return system
+
+    return factory
+
+
+def test_rule_vector_system_is_correct(rules_root):
+    out = run_rules(rules_root)
+    assert out["correct"] is True and out["failed"] == 0
+    # One entry per answered slice (warm-up included), partition and rule.
+    assert out["attempted"] == (out["window"]["slices"] + 2) * 4 * 2
+    assert all(v == {"value": 0, "limit": 0}
+               for v in out["compared"].values())
+
+
+def test_rule_vector_fault_in_one_rule_is_caught(rules_root):
+    def one_rule(call, out):
+        if call == 3:
+            out[1, 1] += 1
+        return out
+
+    out = run_rules(rules_root, _answers(one_rule))
+    assert out["correct"] is False
+    assert out["compared"]["count_mismatches"]["value"] == 1
+    assert out["failed"] == 1
+
+
+def test_rule_vector_of_wrong_shape_is_not_correct(rules_root):
+    out = run_rules(rules_root, _answers(lambda call, out: out[:, :1]))
+    assert out["correct"] is False
+    assert out["compared"]["count_mismatches"]["value"] == out["attempted"]
+
+
+def test_rule_vector_control_is_not_correct(rules_root):
+    out = run_rules(rules_root, harness.Control)
     assert out["correct"] is False
     assert out["compared"]["count_mismatches"]["value"] > 0
 
