@@ -295,13 +295,13 @@ def bench_superchunk(q: int, k: int, s_cap: int, warm: int, tail: int):
         f"vs {tel_pc.violations}")
     speedup = pc_s / max(sc_s, 1e-9)
     print(f"superchunk,s={s_cap},{sc_s:.3f}s,per_chunk,{pc_s:.3f}s,"
-          f"speedup,{speedup:.2f},host_syncs,{tel_sc.host_syncs}vs"
-          f"{tel_pc.host_syncs},replans,{tel_sc.replans}", flush=True)
+          f"speedup,{speedup:.2f},readbacks,{tel_sc.readbacks}vs"
+          f"{tel_pc.readbacks},replans,{tel_sc.replans}", flush=True)
     return {"superchunk": s_cap, "superchunk_s": round(sc_s, 4),
             "superchunk_per_chunk_s": round(pc_s, 4),
             "superchunk_speedup": round(speedup, 3),
-            "superchunk_host_syncs": tel_sc.host_syncs,
-            "per_chunk_host_syncs": tel_pc.host_syncs,
+            "superchunk_readbacks": tel_sc.readbacks,
+            "per_chunk_readbacks": tel_pc.readbacks,
             "superchunk_replans": tel_sc.replans}
 
 

@@ -47,14 +47,17 @@ covers the rule set itself:
   still deploy on the very next chunk and counters stay bit-identical to
   per-chunk stepping for every S.
 
-Counter semantics are the serving front's: immediate deployment, no
-migration split, exactly-once chunked counting — and per-rule counters are
-bit-identical to Q independent Sessions over the same stream (the bench
-and property tests gate this).
+The served front is ``process(type_id, ts, attr, keys, t0, t1)``, the
+session's: one keyed batch per slice, routed by ``key % K``, one
+``cep.process`` span per call.  Counter semantics are the serving
+front's: immediate deployment, no migration split, exactly-once chunked
+counting — and per-rule counters are bit-identical to Q independent
+Sessions over the same stream (the bench and property tests gate this).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -64,7 +67,7 @@ import numpy as np
 
 from ..core import spans
 from ..core.engine import Chunk, EngineConfig, make_spec
-from ..core.fleet import stack_chunks
+from ..core.fleet import route_events, stack_chunks
 from ..core.greedy import greedy_order_plan
 from ..core.invariants import LoweredInvariants
 from ..core.multipattern import (BucketSpec, RuleOps, ShareOps,
@@ -461,8 +464,10 @@ class _OrderRow:
 class Rulebook:
     """Q patterns, one compiled data plane per arity bucket.
 
-    Construct via :func:`open_rulebook`.  ``step``/``run`` advance every
-    rule at once; ``add_rule``/``remove_rule`` mutate the rule set live.
+    Construct via :func:`open_rulebook`.  ``process`` (one keyed batch,
+    routed as the session routes it), ``step`` (one stacked chunk) and
+    ``run`` (a chunk stream) advance every rule at once;
+    ``add_rule``/``remove_rule`` mutate the rule set live.
     """
 
     def __init__(self, rules: Sequence, *, partitions: int = 1,
@@ -489,7 +494,9 @@ class Rulebook:
         self._rules: List[_RuleEntry] = []
         self._buckets: List[_Bucket] = []
         self._chunks = 0
-        self._host_syncs = 0
+        self._events = 0
+        self._readbacks = 0
+        self._dropped = 0
         self._build(patterns)
 
     # -- construction -------------------------------------------------------
@@ -611,17 +618,57 @@ class Rulebook:
         cell inside the call.  The tick writes the session's span names
         (``core.spans``), with the tick index as argument.
         """
+        return self._tick(lambda: self._check_chunk(chunk), t0, t1)[0]
+
+    def process(self, type_id, ts, attr, keys, t0: float, t1: float, *,
+                closure: bool = False):
+        """Route one keyed event batch (``key % K``) covering ``(t0, t1]``
+        and tick every rule once; returns this slice's (R, K) full-match
+        counts, as :meth:`step` does.  With ``closure=True`` returns
+        ``(full, closure)``, both (R, K): ``closure`` is the slice's Kleene
+        companion count per rule and partition (DESIGN.md §4; 0 for rules
+        without a Kleene element).
+
+        Routing is the session's (``core.fleet.route_events``): events
+        over ``config.chunk_capacity`` in a partition are dropped and
+        counted in ``telemetry().dropped``.  The call is one
+        ``cep.process`` span, with the routing as its ``cep.route``.
+        """
+        def route() -> Chunk:
+            chunk, dropped = route_events(
+                np.asarray(type_id), np.asarray(ts), np.asarray(attr),
+                np.asarray(keys), self.k, self.config.chunk_capacity)
+            self._dropped += dropped
+            self._events += len(np.asarray(type_id))
+            return self._check_chunk(chunk)
+
+        full, comp = self._tick(route, t0, t1)
+        return (full, comp) if closure else full
+
+    def _tick(self, route, t0: float, t1: float):
+        """One slice: a ``cep.process`` span around ``route()`` (its
+        ``cep.route``, returning the stacked chunk) and the step."""
         tick = self._chunks
         with jax.profiler.TraceAnnotation(spans.PROCESS, chunk=tick):
+            with jax.profiler.TraceAnnotation(spans.ROUTE, chunk=tick):
+                chunk = route()
             return self._step(chunk, t0, t1, tick)
 
+    @contextlib.contextmanager
+    def _readback(self, tick: int):
+        """One blocking device→host read: a ``cep.readback`` span and a
+        count in ``telemetry().readbacks``."""
+        with jax.profiler.TraceAnnotation(spans.READBACK, chunk=tick):
+            yield
+        self._readbacks += 1
+
     def _step(self, chunk: Chunk, t0: float, t1: float,
-              tick: int) -> np.ndarray:
-        with jax.profiler.TraceAnnotation(spans.ROUTE, chunk=tick):
-            chunk = self._check_chunk(chunk)
+              tick: int) -> Tuple[np.ndarray, np.ndarray]:
+        """The tick's (R, K) full-match and Kleene companion counts."""
         t0j, t1j = jnp.float32(t0), jnp.float32(t1)
         self._chunks += 1
         out = np.zeros((len(self._rules), self.k), np.int64)
+        closure = np.zeros_like(out)
         for bucket in self._buckets:
             with jax.profiler.TraceAnnotation(spans.STEP, chunk=tick):
                 if self.monitored:
@@ -636,11 +683,10 @@ class Rulebook:
                         bucket.state, chunk, bucket.ops_device(),
                         bucket.share_d, bucket.plans_device(), t0j, t1j)
             # One coalesced counter transfer per bucket per tick.
-            with jax.profiler.TraceAnnotation(spans.READBACK, chunk=tick):
+            with self._readback(tick):
                 cnt = np.asarray(jnp.stack(
                     [res.full, res.pm, res.overflow, res.closure,
                      res.neg]))
-            self._host_syncs += 1
             for q, entry in enumerate(bucket.slots):
                 if entry is None or not entry.active:
                     continue
@@ -652,12 +698,13 @@ class Rulebook:
                 entry.neg_rejected += int(cnt[4, :, q].sum())
                 entry.chunks += 1
                 out[entry.rid] = full_k
+                closure[entry.rid] = cnt[3, :, q]
             if self.monitored:
-                with jax.profiler.TraceAnnotation(spans.READBACK, chunk=tick):
+                with self._readback(tick):
                     fired = np.nonzero(np.asarray(violated))
                 with jax.profiler.TraceAnnotation(spans.CONTROL, chunk=tick):
                     self._apply_flags(bucket, fired, rates, sel, tick)
-        return out
+        return out, closure
 
     def _apply_flags(self, bucket: _Bucket, fired, rates, sel,
                      tick: int) -> None:
@@ -665,38 +712,38 @@ class Rulebook:
             return
         # One coalesced stats transfer serves every fired cell; per-cell
         # device indexing costs a sync each.
-        self._host_syncs += 1
-        with jax.profiler.TraceAnnotation(spans.READBACK, chunk=tick):
-            rates_h = np.asarray(rates, np.float64)
-            sel_h = np.asarray(sel, np.float64)
+        with self._readback(tick):
+            rates_h, sel_h = (np.asarray(x, np.float64)
+                              for x in jax.device_get((rates, sel)))
         for k, q in zip(*fired):
-            with jax.profiler.TraceAnnotation(
-                    spans.REPLAN, chunk=tick, partition=int(k)):
-                self._replan_cell(bucket, int(k), int(q), rates_h, sel_h)
+            self._replan_cell(bucket, int(k), int(q), rates_h, sel_h, tick)
 
     def _replan_cell(self, bucket: _Bucket, k: int, q: int,
-                     rates, sel) -> None:
+                     rates, sel, tick: int) -> None:
         """Invariant violation at cell (k, q): re-run the planner on that
-        cell's device statistics and deploy plan + invariant rows."""
+        cell's device statistics and deploy plan + invariant rows, in one
+        ``cep.replan`` span (arguments ``partition`` and ``rule``)."""
         entry = bucket.slots[q]
         if entry is None or not entry.active:
             return
-        entry.violations += 1
-        stat = Stat(np.asarray(rates[k, q], np.float64),
-                    np.asarray(sel[k, q], np.float64))
-        plan, dcs = greedy_order_plan(entry.pattern, stat,
-                                      pin=entry.pinned)
-        order = np.asarray(plan.order, np.int32)
-        changed = not np.array_equal(order, bucket.plans_h[k, q])
-        bucket.write_plan_row(k, q, order)
-        pol = bucket.policies[k][q]
-        pol.on_replan(plan, dcs, stat)
-        bucket.lowered.write(k, q, pol.compile(
-            bucket.bspec.n, max_inv=bucket.caps[0],
-            max_terms=bucket.caps[1]))
-        entry.replans += 1
-        if changed:
-            entry.deployments += 1
+        with jax.profiler.TraceAnnotation(spans.REPLAN, chunk=tick,
+                                          partition=k, rule=entry.rid):
+            entry.violations += 1
+            stat = Stat(np.asarray(rates[k, q], np.float64),
+                        np.asarray(sel[k, q], np.float64))
+            plan, dcs = greedy_order_plan(entry.pattern, stat,
+                                          pin=entry.pinned)
+            order = np.asarray(plan.order, np.int32)
+            changed = not np.array_equal(order, bucket.plans_h[k, q])
+            bucket.write_plan_row(k, q, order)
+            pol = bucket.policies[k][q]
+            pol.on_replan(plan, dcs, stat)
+            bucket.lowered.write(k, q, pol.compile(
+                bucket.bspec.n, max_inv=bucket.caps[0],
+                max_terms=bucket.caps[1]))
+            entry.replans += 1
+            if changed:
+                entry.deployments += 1
 
     def step_superchunk(self, chunks: Sequence[Chunk],
                         edges: Sequence[Tuple[float, float]]) -> np.ndarray:
@@ -760,10 +807,10 @@ class Rulebook:
                             lowered, xs)
 
         state, monitor, ys = dispatch(xs)
-        full_h, pm_h, ov_h, cl_h, ng_h, vio_h = jax.device_get(
-            (ys.full, ys.pm, ys.overflow, ys.closure, ys.neg,
-             ys.violated))
-        self._host_syncs += 1
+        with self._readback(self._chunks + base):
+            full_h, pm_h, ov_h, cl_h, ng_h, vio_h = jax.device_get(
+                (ys.full, ys.pm, ys.overflow, ys.closure, ys.neg,
+                 ys.violated))
         f = (first_event(vio_h, ov_h, n_en, escalate=False)
              if self.monitored else None)
         if f is not None and f < n_en - 1:
@@ -774,10 +821,10 @@ class Rulebook:
             en[:f + 1] = True
             state, monitor, ys = dispatch(
                 xs._replace(enabled=jnp.asarray(en)))
-            full_h, pm_h, ov_h, cl_h, ng_h, vio_h = jax.device_get(
-                (ys.full, ys.pm, ys.overflow, ys.closure, ys.neg,
-                 ys.violated))
-            self._host_syncs += 1
+            with self._readback(self._chunks + base):
+                full_h, pm_h, ov_h, cl_h, ng_h, vio_h = jax.device_get(
+                    (ys.full, ys.pm, ys.overflow, ys.closure, ys.neg,
+                     ys.violated))
         accept = n_en if f is None else f + 1
         bucket.state, bucket.monitor = state, monitor
         for q, entry in enumerate(bucket.slots):
@@ -796,13 +843,13 @@ class Rulebook:
             fired = np.nonzero(vio_h[last])
             if fired[0].size:
                 # One coalesced stats transfer serves every fired cell.
-                self._host_syncs += 1
-                rates_h = np.asarray(
-                    jax.device_get(ys.rates[last]), np.float64)
-                sel_h = np.asarray(jax.device_get(ys.sel[last]), np.float64)
+                with self._readback(self._chunks + base):
+                    rates_h, sel_h = (np.asarray(x, np.float64) for x in
+                                      jax.device_get((ys.rates[last],
+                                                      ys.sel[last])))
                 for k, q in zip(*fired):
                     self._replan_cell(bucket, int(k), int(q),
-                                      rates_h, sel_h)
+                                      rates_h, sel_h, self._chunks + base)
         return accept
 
     def run(self, stream: Stream) -> Telemetry:
@@ -831,8 +878,8 @@ class Rulebook:
         after = self.telemetry()
         delta = Telemetry(partitions=self.k)
         for f in ("chunks", "matches", "replans", "deployments",
-                  "violations", "host_syncs", "overflow", "neg_rejected",
-                  "closure_expansions"):
+                  "violations", "readbacks", "overflow",
+                  "neg_rejected", "closure_expansions"):
             setattr(delta, f, getattr(after, f) - getattr(before, f))
         if after.per_partition_matches is not None:
             base = (before.per_partition_matches
@@ -1007,7 +1054,9 @@ class Rulebook:
             tel.violations += e.violations
         tel.chunks = (self._entry(rule).chunks if rule is not None
                       else self._chunks)
-        tel.host_syncs = self._host_syncs
+        tel.events = self._events
+        tel.readbacks = self._readbacks
+        tel.dropped = self._dropped
         return tel
 
     def reset(self) -> None:
@@ -1025,7 +1074,9 @@ class Rulebook:
             e.overflow = e.neg_rejected = e.closure_expansions = 0
             e.pm_created = e.chunks = 0
         self._chunks = 0
-        self._host_syncs = 0
+        self._events = 0
+        self._readbacks = 0
+        self._dropped = 0
 
 
 def open_rulebook(rules: Iterable, *, partitions: int = 1,

@@ -84,7 +84,9 @@ class Telemetry:
     ``readbacks`` counts the blocking device→host reads of the
     incremental plane: one for the slice's counters, one for a monitored
     session's flags and drift, and two (``rates[p]``, ``sel[p]``) per
-    flagged partition.
+    flagged partition; a rulebook's are the counters and the flags of
+    each bucket, and one statistics pull per bucket that flagged a cell
+    (a rulebook counts its reads there alone: its ``host_syncs`` is 0).
     ``events`` is maintained by ``run`` and ``process`` — ``step`` skips
     it to avoid a per-tick device sync.
     """

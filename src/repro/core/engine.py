@@ -144,7 +144,7 @@ def _validity_rows(l_valid, r_valid, m, b):
 
 
 def _window_rows(l_min, l_max, r_min, r_max, window):
-    # span(L ∪ R) <= W  ⇔  maxL < minR + W  ∧  minL > maxR − W.
+    # span(L ∪ R) < W  ⇔  maxL < minR + W  ∧  minL > maxR − W.
     return [
         (l_max, r_min, _LT, float(window)),
         (l_min, r_max, _GT, float(window)),

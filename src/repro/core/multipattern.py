@@ -55,6 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import ops as kops
+from . import spans
 from .engine import (Buffers, Chunk, EngineConfig, MatchSet, PredicateStrips,
                      _compact, _row_counts, _rows_to_stacks, _validity_rows,
                      make_spec)
@@ -325,6 +326,7 @@ def build_rule_strips(bspec: BucketSpec, ops: RuleOps,
         hi_idx=jnp.stack(hi_steps))
 
 
+@jax.named_scope(spans.INGEST)
 def _rule_ingest(bspec: BucketSpec, cfg: EngineConfig, buffers: Buffers,
                  chunk: Chunk, type_rows) -> Buffers:
     """Route chunk events into one rule's ring rows (``engine._ingest``
@@ -343,6 +345,7 @@ def _rule_ingest(bspec: BucketSpec, cfg: EngineConfig, buffers: Buffers,
     return Buffers(ts, attr, valid, ptr)
 
 
+@jax.named_scope(spans.JOIN)
 def _rule_leaf(bspec: BucketSpec, cfg: EngineConfig, buffers: Buffers,
                row, pos, t0, window, out_rows: int) -> MatchSet:
     """One buffer row as a single-position match set (``engine._leaf`` with
@@ -374,20 +377,80 @@ def _rule_step(bspec: BucketSpec, cfg: EngineConfig, buffers: Buffers,
     of ``OrderEngine``'s ``packed_step``; thresholds come from the rule's
     packed ``ths`` strip instead of trace constants)."""
     R = _rule_leaf(bspec, cfg, buffers, q, q, t0, ops.window, cfg.b_cap)
-    attr_b = buffers.attr[q]
-    Lr = [pm.max_ts, pm.min_ts, pm.ts[:, lo], pm.ts[:, hi]]
-    Rr = [R.min_ts, R.max_ts, R.min_ts, R.min_ts]
-    for (a, b_) in _ordered_pairs(bspec.n):
-        Lr.append(pm.attr[:, a, ops.a_attr[a, b_]])
-        Rr.append(attr_b[:, ops.b_attr[a, b_]])
-    Ls = jnp.stack([x.astype(jnp.float32) for x in Lr])
-    Rs = jnp.stack([x.astype(jnp.float32) for x in Rr])
-    ok = kops.window_join_packed(Ls, Rs, sops, ops.ths, pm.valid, R.valid,
-                                 backend=cfg.backend)
-    created = ok.sum().astype(jnp.int32)
+    with jax.named_scope(spans.JOIN):
+        attr_b = buffers.attr[q]
+        Lr = [pm.max_ts, pm.min_ts, pm.ts[:, lo], pm.ts[:, hi]]
+        Rr = [R.min_ts, R.max_ts, R.min_ts, R.min_ts]
+        for (a, b_) in _ordered_pairs(bspec.n):
+            Lr.append(pm.attr[:, a, ops.a_attr[a, b_]])
+            Rr.append(attr_b[:, ops.b_attr[a, b_]])
+        Ls = jnp.stack([x.astype(jnp.float32) for x in Lr])
+        Rs = jnp.stack([x.astype(jnp.float32) for x in Rr])
+        ok = kops.window_join_packed(Ls, Rs, sops, ops.ths, pm.valid,
+                                     R.valid, backend=cfg.backend)
+        created = ok.sum().astype(jnp.int32)
     return _compact(pm, R, ok, created, cfg.m_cap)
 
 
+@jax.named_scope(spans.NEGATE)
+def _rule_negate(bspec: BucketSpec, cfg: EngineConfig, ops: RuleOps,
+                 buffers: Buffers, pm: MatchSet, completed, t0):
+    """Negation veto: drop the completed matches with a compatible event
+    of the negated type (ring row ``n``) between the negated position's
+    neighbours; returns the surviving mask and the vetoed count."""
+    n, m, b = bspec.n, pm.valid.shape[0], cfg.b_cap
+    W = ops.window
+    nts = buffers.ts[n]
+    nvalid = buffers.valid[n] & (nts > t0 - W)
+    rows = _validity_rows(completed, nvalid, m, b)
+    rows += [(pm.max_ts, nts, _LT, W), (pm.min_ts, nts, _GT, W)]
+    np_ = ops.neg_pos
+    rows.append((pm.ts[:, jnp.clip(np_ - 1, 0, n - 1)], nts,
+                 jnp.where(np_ > 0, _LT, _NONE), 0.0))
+    rows.append((pm.ts[:, jnp.clip(np_, 0, n - 1)], nts,
+                 jnp.where(np_ < n, _GT, _NONE), 0.0))
+    for i in range(bspec.neg_rows_cap):  # static loop, op-gated rows
+        rows.append((pm.attr[:, ops.neg_row_pos[i], ops.neg_row_ma[i]],
+                     buffers.attr[n][:, ops.neg_row_na[i]],
+                     ops.neg_row_op[i], ops.neg_row_th[i]))
+    cnt = _row_counts(cfg, rows, m, b)
+    veto = (cnt > 0) & ops.has_neg  # fused buckets: gate per rule
+    neg_rejected = (completed & veto).sum().astype(jnp.int32)
+    return completed & ~veto, neg_rejected
+
+
+@jax.named_scope(spans.KLEENE)
+def _rule_kleene(bspec: BucketSpec, cfg: EngineConfig, ops: RuleOps,
+                 buffers: Buffers, pm: MatchSet, completed, t0):
+    """Kleene companion count of the completed matches: the compatible
+    events at the Kleene position besides the match's own, capped at the
+    rule's bound."""
+    n, m, b = bspec.n, pm.valid.shape[0], cfg.b_cap
+    W = ops.window
+    kp = ops.kleene_pos
+    kts = buffers.ts[kp]
+    kvalid = buffers.valid[kp] & (kts > t0 - W)
+    attr_k = buffers.attr[kp]
+    rows = _validity_rows(completed, kvalid, m, b)
+    rows += [(pm.max_ts, kts, _LT, W), (pm.min_ts, kts, _GT, W)]
+    rows.append((pm.ts[:, jnp.clip(kp - 1, 0, n - 1)], kts,
+                 jnp.where(ops.is_seq & (kp > 0), _LT, _NONE), 0.0))
+    rows.append((pm.ts[:, jnp.clip(kp + 1, 0, n - 1)], kts,
+                 jnp.where(ops.is_seq & (kp < n - 1), _GT, _NONE), 0.0))
+    for o in range(n):  # static loop over partner positions
+        op = jnp.where(o == kp, _NONE, ops.op_t[o, kp])
+        rows.append((pm.attr[:, o, ops.a_attr[o, kp]],
+                     attr_k[:, ops.b_attr[o, kp]],
+                     op, ops.theta[o, kp]))
+    cnt = _row_counts(cfg, rows, m, b)
+    comp = jnp.minimum(jnp.maximum(cnt - 1, 0), ops.kleene_bound)
+    # Non-Kleene rules in a fused bucket point kleene_pos at a real
+    # row; gating (not just masking padding) is what keeps them exact.
+    return jnp.where(ops.has_kleene & completed, comp,
+                     0).sum().astype(jnp.int32)
+
+
+@jax.named_scope(spans.FINALIZE)
 def _rule_finalize(bspec: BucketSpec, cfg: EngineConfig, ops: RuleOps,
                    buffers: Buffers, pm: MatchSet, t0, t1):
     """Completion filter + negation veto + Kleene count for one rule.
@@ -400,58 +463,16 @@ def _rule_finalize(bspec: BucketSpec, cfg: EngineConfig, ops: RuleOps,
     rule-varying pieces (positions, ops, thetas, the window) are traced.
     Window rows are inlined (the engine's ``_window_rows`` casts the
     window to a Python float, which a traced per-rule window cannot do).
+    The two post-blocks are the scopes ``cep.negate`` and ``cep.kleene``.
     """
-    n = bspec.n
-    m = pm.valid.shape[0]
-    b = cfg.b_cap
-    W = ops.window
     completed = pm.valid & (pm.max_ts > t0) & (pm.max_ts <= t1)
     neg_rejected = jnp.int32(0)
-
     if bspec.has_neg:
-        row = n
-        nts = buffers.ts[row]
-        nvalid = buffers.valid[row] & (nts > t0 - W)
-        rows = _validity_rows(completed, nvalid, m, b)
-        rows += [(pm.max_ts, nts, _LT, W), (pm.min_ts, nts, _GT, W)]
-        np_ = ops.neg_pos
-        rows.append((pm.ts[:, jnp.clip(np_ - 1, 0, n - 1)], nts,
-                     jnp.where(np_ > 0, _LT, _NONE), 0.0))
-        rows.append((pm.ts[:, jnp.clip(np_, 0, n - 1)], nts,
-                     jnp.where(np_ < n, _GT, _NONE), 0.0))
-        for i in range(bspec.neg_rows_cap):  # static loop, op-gated rows
-            rows.append((pm.attr[:, ops.neg_row_pos[i], ops.neg_row_ma[i]],
-                         buffers.attr[row][:, ops.neg_row_na[i]],
-                         ops.neg_row_op[i], ops.neg_row_th[i]))
-        cnt = _row_counts(cfg, rows, m, b)
-        veto = (cnt > 0) & ops.has_neg  # fused buckets: gate per rule
-        neg_rejected = (completed & veto).sum().astype(jnp.int32)
-        completed = completed & ~veto
-
+        completed, neg_rejected = _rule_negate(bspec, cfg, ops, buffers, pm,
+                                               completed, t0)
     closure = jnp.int32(0)
     if bspec.has_kleene:
-        kp = ops.kleene_pos
-        kts = buffers.ts[kp]
-        kvalid = buffers.valid[kp] & (kts > t0 - W)
-        attr_k = buffers.attr[kp]
-        rows = _validity_rows(completed, kvalid, m, b)
-        rows += [(pm.max_ts, kts, _LT, W), (pm.min_ts, kts, _GT, W)]
-        rows.append((pm.ts[:, jnp.clip(kp - 1, 0, n - 1)], kts,
-                     jnp.where(ops.is_seq & (kp > 0), _LT, _NONE), 0.0))
-        rows.append((pm.ts[:, jnp.clip(kp + 1, 0, n - 1)], kts,
-                     jnp.where(ops.is_seq & (kp < n - 1), _GT, _NONE), 0.0))
-        for o in range(n):  # static loop over partner positions
-            op = jnp.where(o == kp, _NONE, ops.op_t[o, kp])
-            rows.append((pm.attr[:, o, ops.a_attr[o, kp]],
-                         attr_k[:, ops.b_attr[o, kp]],
-                         op, ops.theta[o, kp]))
-        cnt = _row_counts(cfg, rows, m, b)
-        comp = jnp.minimum(jnp.maximum(cnt - 1, 0), ops.kleene_bound)
-        # Non-Kleene rules in a fused bucket point kleene_pos at a real
-        # row; gating (not just masking padding) is what keeps them exact.
-        closure = jnp.where(ops.has_kleene & completed, comp,
-                            0).sum().astype(jnp.int32)
-
+        closure = _rule_kleene(bspec, cfg, ops, buffers, pm, completed, t0)
     return completed.sum().astype(jnp.int32), neg_rejected, closure
 
 
@@ -575,10 +596,12 @@ def _make_bucket_step(bspec: BucketSpec, cfg: EngineConfig,
         return bucket_step
 
     def mon_one(ops, monitor, lowered, chunk, t0, t1):
-        counts, trials, hits = _observe_one(bspec, ops, chunk)
-        monitor = monitor_update(monitor, counts, t1 - t0, trials, hits)
-        rates, sel = monitor_snapshot(monitor, laplace)
-        violated, drift = eval_lowered(lowered, rates, sel)
+        with jax.named_scope(spans.MONITOR):
+            counts, trials, hits = _observe_one(bspec, ops, chunk)
+            monitor = monitor_update(monitor, counts, t1 - t0, trials, hits)
+            rates, sel = monitor_snapshot(monitor, laplace)
+        with jax.named_scope(spans.VERIFY):
+            violated, drift = eval_lowered(lowered, rates, sel)
         return monitor, violated, drift, rates, sel
 
     def bucket_step_monitored(state, monitor, chunk, ops, share, plans,

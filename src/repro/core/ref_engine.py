@@ -4,7 +4,8 @@ A deliberately slow, pure-Python/numpy re-implementation of the detection
 semantics the vectorized engine (``engine.py``) promises:
 
 * SEQ / AND over ``n`` primitive event types with pairwise structural
-  predicates and a sliding time window (span ≤ W);
+  predicates and a sliding time window: span (latest − earliest) strictly
+  below W, as the engine's strict window rows give it;
 * chunked **exactly-once** counting — a match is counted in the chunk
   ``(t0, t1]`` containing its latest event;
 * negation as a veto: a completed match is discarded when any event of the
@@ -74,7 +75,7 @@ def _neg_vetoed(pattern: Pattern, combo_idx, tss, tid, ts, attr) -> bool:
         tj = ts[j]
         if not (lo < tj < hi):
             continue
-        if max(tss.max(), tj) - min(tss.min(), tj) > pattern.window:
+        if max(tss.max(), tj) - min(tss.min(), tj) >= pattern.window:
             continue
         ok = True
         for pr in pattern.negated_predicates:
@@ -104,7 +105,7 @@ def _closure_count(pattern: Pattern, pt, combo_idx, tss, tid, ts,
         tj = ts[j]
         if not (lo < tj < hi):
             continue
-        if max(tss.max(), tj) - min(tss.min(), tj) > pattern.window:
+        if max(tss.max(), tj) - min(tss.min(), tj) >= pattern.window:
             continue
         ok = True
         for p in range(n):
@@ -139,7 +140,7 @@ def brute_force_matches(
     for combo in itertools.product(*idx_by_pos):
         combo = list(combo)
         tss = ts[combo]
-        if tss.max() - tss.min() > pattern.window:
+        if tss.max() - tss.min() >= pattern.window:
             continue
         if not (t0 < tss.max() <= t1):
             continue
